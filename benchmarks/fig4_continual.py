@@ -69,4 +69,6 @@ def run(fast: bool = True) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.utils import enable_compile_cache
+    enable_compile_cache()
     run()

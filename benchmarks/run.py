@@ -34,6 +34,8 @@ def smoke() -> None:
                             fig5b_endurance, fig5c_latency, fig5d_power,
                             kernel_bench, roofline_bench,
                             table1_throughput)
+    from repro.utils import enable_compile_cache
+    enable_compile_cache()
     t0 = time.time()
     print("name,us_per_call,derived")
     table1_throughput.run(fast=True)

@@ -219,4 +219,6 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from repro.utils import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

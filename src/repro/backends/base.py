@@ -52,6 +52,7 @@ from repro.faults.model import (FaultSpec, advance_wear, apply_cell_faults,
                                 apply_read_upsets, fault_state,
                                 mask_updates, sample_fault_state)
 from repro.telemetry.meters import Telemetry
+from repro.utils import zeros_like_varying
 
 PyTree = dict[str, jax.Array]
 
@@ -280,7 +281,8 @@ class DeviceBackend(abc.ABC):
             return (h_new, k), (h_new, h, pre)
 
         if h0 is None:
-            h0 = jnp.zeros((B, cfg.n_h), cfg.dtype)
+            h0 = zeros_like_varying((B, cfg.n_h), cfg.dtype, x_seq, params,
+                                    key)
         with self.telemetry.scaled(T):
             (_, _), (h_all, h_prev, pre) = jax.lax.scan(
                 step, (h0, key), jnp.swapaxes(x_seq, 0, 1))

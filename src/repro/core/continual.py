@@ -608,8 +608,10 @@ class BatchSchedule:
 # chain) shows up against this constant before it silently breaks
 # loop/compiled bit-parity. Asserted in tests/test_determinism.py and
 # gated in benchmarks/scenarios_grid.py (the bench-scenarios CI job).
-GOLDEN_PERMUTED_SCHEDULE_SHA256 = ("2fe9e2b677cf741551717cd54502398f"
-                                   "ddf8094b6d6ab35df1ec113f068b12ee")
+# The quantizer key chain draws under JAX's default partitionable
+# threefry, so the digest is pinned to the installed JAX.
+GOLDEN_PERMUTED_SCHEDULE_SHA256 = ("9c23aeecf199da424f9dc94cc8ce9edc"
+                                   "65bb2f43e528e1b20116e593363d32f8")
 
 
 def _stream_context(tasks: list[TaskData]) -> dict[str, int]:

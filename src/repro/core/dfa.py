@@ -100,7 +100,12 @@ def dfa_grads(params: dict[str, jax.Array], psi: jax.Array, cfg: MiRUConfig,
             aux["h_all"],
             jnp.broadcast_to(idx, (B, 1, aux["h_all"].shape[-1])),
             axis=1)[:, 0, :]
-    g_wo = h_T.T @ delta_o
+    # Written as the transpose of delta_oᵀ·h_T, not h_Tᵀ·delta_o: XLA's
+    # CPU backend then keeps one contraction order whether or not the
+    # step runs under vmap, so a fleet chip (vmapped once) computes the
+    # same bits as a seed of the vmapped sweep (tests/test_fleet.py,
+    # mesh invariance). Unbatched results are unchanged by the rewrite.
+    g_wo = (delta_o.T @ h_T).T
     g_bo = jnp.sum(delta_o, axis=0)
 
     # Hidden layer (lines 12-17). e is shared across time.

@@ -38,7 +38,6 @@ from typing import Any, Optional, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.backends import DeviceBackend, get_backend
@@ -71,6 +70,13 @@ def fleet_shard_count(n_devices: int,
     return max(d for d in range(1, avail + 1) if n_devices % d == 0)
 
 
+def _fetch(res: dict) -> tuple[dict, list[int]]:
+    """Host copies of the sharded run outputs, and the id of the device
+    that held each shard of them."""
+    devices = [s.device.id for s in res["R_full"].addressable_shards]
+    return jax.tree.map(np.asarray, res), devices
+
+
 def run_fleet(cfg, spec: TrainerSpec, tasks: list[TaskData],
               fleet: FleetSpec,
               replay: Optional[ReplaySpec] = None,
@@ -94,6 +100,7 @@ def run_fleet(cfg, spec: TrainerSpec, tasks: list[TaskData],
                         to per-chip lifetime projection
       n_shards          mesh size actually used (largest divisor of the
                         fleet size that fits the available devices)
+      shard_devices     id of the device that held each output shard
       metrics/metrics_std  fleet mean/std, as in the seed-vmapped path
 
     ``shard_data=True`` turns the fleet into a data-parallel consumer of
@@ -211,9 +218,9 @@ def run_fleet(cfg, spec: TrainerSpec, tasks: list[TaskData],
     # the shard-local copies alias in place. The deferred telemetry
     # callback fires once per shard over the n_local-scaled deltas, so
     # the counter totals are mesh-shape invariant.
-    fn = jax.jit(shard_map(vrun, mesh=mesh,
-                           in_specs=(ax,) * 8 + (P(), P()),
-                           out_specs=ax),
+    fn = jax.jit(jax.shard_map(vrun, mesh=mesh,
+                               in_specs=(ax,) * 8 + (P(), P()),
+                               out_specs=ax),
                  donate_argnums=(0, 2))
     t0 = time.perf_counter()
     compile_s = execute_s = None
@@ -229,13 +236,13 @@ def run_fleet(cfg, spec: TrainerSpec, tasks: list[TaskData],
         compile_s = time.perf_counter() - t0
         t1 = time.perf_counter()
         with tracer.span("execute", backend=backend.name, n_devices=D):
-            res = compiled_fn(*stacked, eval_x, eval_y)
-            res = jax.tree.map(np.asarray, res)
+            res, shard_devices = _fetch(compiled_fn(*stacked, eval_x,
+                                                    eval_y))
         execute_s = time.perf_counter() - t1
     else:
         with tele.scaled(n_local):
             res = fn(*stacked, eval_x, eval_y)
-        res = jax.tree.map(np.asarray, res)
+        res, shard_devices = _fetch(res)
     wall_s = time.perf_counter() - t0
     obs_streams = res.pop("obs", None)
 
@@ -264,6 +271,7 @@ def run_fleet(cfg, spec: TrainerSpec, tasks: list[TaskData],
         "fleet": fleet,
         "n_devices": D,
         "n_shards": n_shards,
+        "shard_devices": shard_devices,
         "n_local": n_local,
         "wall_s": wall_s,
         "steps_per_task": S,

@@ -17,6 +17,20 @@ ops.py — public jit'd wrappers (padding, dispatch, interpret-mode on CPU).
 ref.py — pure-jnp oracles; every kernel is swept against them in
 tests/test_kernels.py across shapes and dtypes.
 """
+import re
+
 from repro.kernels import ops, ref
 
-__all__ = ["ops", "ref"]
+__all__ = ["ops", "ref", "compiled_kernels"]
+
+_TPU_KERNEL_CALL = re.compile(
+    r"%([A-Za-z_]\w*)(?:\.\d+)* = [^\n]*custom_call_target=\"tpu_custom_call\"")
+
+
+def compiled_kernels(hlo_text: str) -> set[str]:
+    """Names of the Pallas kernels a compiled TPU program calls, read
+    from ``Compiled.as_text()``: each kernel passes its ``name`` to
+    ``pallas_call``, and the compiler names the ``tpu_custom_call``
+    instruction after it (``%wbs_miru_scan.3``). A program that fell back
+    to a jnp reference has no such call."""
+    return set(_TPU_KERNEL_CALL.findall(hlo_text))

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.utils import varying_axes
+
 
 def _kwta_kernel(x_ref, out_ref, *, k: int, iters: int):
     x = x_ref[...].astype(jnp.float32)
@@ -55,6 +57,8 @@ def kwta_pallas(x: jax.Array, k: int, iters: int = 32, br: int = 8,
         grid=(R // br,),
         in_specs=[pl.BlockSpec((br, N), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, N), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, N), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((R, N), x.dtype,
+                                       vma=varying_axes(x)),
         interpret=interpret,
+        name="kwta",
     )(x)

@@ -10,9 +10,12 @@ tiles are the concurrent units ("tiles work concurrently at the layer
 level"), time steps are sequential within each tile, and the carried
 h never leaves VMEM between steps (the paper's shift-register file).
 
-Grid = (B/bm, T), T innermost ⇒ for a fixed batch tile the kernel visits
-t = 0..T−1 in order; `h_scratch` is the carried state, re-seeded from h0
-at t == 0.
+Layout: time-major (T, B, H) with (tc, bm, H) blocks — batch tile on the
+sublanes (bm a multiple of 8), H on the lanes (128-aligned), time on the
+untiled leading dim. Grid = (B/bm, T/tc), time chunks innermost ⇒ for a
+fixed batch tile the kernel visits the chunks in order and loops over
+the tc steps inside each; ``h_scratch`` is the carried state, re-seeded
+from h0 at the first chunk.
 """
 from __future__ import annotations
 
@@ -23,60 +26,66 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.utils import varying_axes
+
 
 def _miru_kernel(xw_ref, u_ref, h0_ref, hall_ref, pre_ref, h_scratch, *,
-                 beta: float, lam: float):
-    t = pl.program_id(1)
+                 beta: float, lam: float, tc: int):
+    c = pl.program_id(1)
 
-    @pl.when(t == 0)
+    @pl.when(c == 0)
     def _seed():
         h_scratch[...] = h0_ref[...].astype(jnp.float32)
 
-    h = h_scratch[...]
     u = u_ref[...].astype(jnp.float32)
-    pre = xw_ref[:, 0, :].astype(jnp.float32) + jnp.dot(
-        beta * h, u, preferred_element_type=jnp.float32)
-    h_new = lam * h + (1.0 - lam) * jnp.tanh(pre)
-    h_scratch[...] = h_new
-    hall_ref[:, 0, :] = h_new
-    pre_ref[:, 0, :] = pre
+
+    def step(s, h):
+        pre = xw_ref[s].astype(jnp.float32) + jnp.dot(
+            beta * h, u, preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST)
+        h_new = lam * h + (1.0 - lam) * jnp.tanh(pre)
+        hall_ref[s] = h_new
+        pre_ref[s] = pre
+        return h_new
+
+    h_scratch[...] = jax.lax.fori_loop(0, tc, step, h_scratch[...])
 
 
-@functools.partial(jax.jit, static_argnames=("beta", "lam", "bm",
+@functools.partial(jax.jit, static_argnames=("beta", "lam", "bm", "tc",
                                              "interpret"))
 def miru_scan_pallas(xw: jax.Array, u_h: jax.Array, h0: jax.Array,
-                     beta: float, lam: float, bm: int = 8,
+                     beta: float, lam: float, bm: int = 8, tc: int = 1,
                      interpret: bool = False
                      ) -> tuple[jax.Array, jax.Array]:
-    """xw (B, T, H) precomputed input drive; u_h (H, H); h0 (B, H).
+    """xw (T, B, H) time-major precomputed input drive; u_h (H, H);
+    h0 (B, H).
 
-    Returns (h_all, pre), both (B, T, H) f32. B must divide by bm and H
-    should be 128-aligned (ops.py pads).
+    Returns (h_all, pre), both (T, B, H) f32. B must divide by bm (a
+    multiple of 8), T by tc, and H should be 128-aligned (ops.py pads).
     """
-    B, T, H = xw.shape
-    assert B % bm == 0, (B, bm)
+    T, B, H = xw.shape
+    assert B % bm == 0 and bm % 8 == 0, (B, bm)
+    assert T % tc == 0, (T, tc)
     assert u_h.shape == (H, H) and h0.shape == (B, H)
 
-    grid = (B // bm, T)
     kernel = functools.partial(_miru_kernel, beta=float(beta),
-                               lam=float(lam))
+                               lam=float(lam), tc=tc)
+    seq = pl.BlockSpec((tc, bm, H), lambda i, c: (c, i, 0))
     h_all, pre = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(B // bm, T // tc),
         in_specs=[
-            pl.BlockSpec((bm, 1, H), lambda i, t: (i, t, 0)),  # xw
-            pl.BlockSpec((H, H), lambda i, t: (0, 0)),         # u_h
-            pl.BlockSpec((bm, H), lambda i, t: (i, 0)),        # h0
+            seq,                                               # xw
+            pl.BlockSpec((H, H), lambda i, c: (0, 0)),         # u_h
+            pl.BlockSpec((bm, H), lambda i, c: (i, 0)),        # h0
         ],
-        out_specs=[
-            pl.BlockSpec((bm, 1, H), lambda i, t: (i, t, 0)),  # h_all
-            pl.BlockSpec((bm, 1, H), lambda i, t: (i, t, 0)),  # pre
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, T, H), jnp.float32),
-            jax.ShapeDtypeStruct((B, T, H), jnp.float32),
-        ],
+        out_specs=[seq, seq],                                  # h_all, pre
+        out_shape=[jax.ShapeDtypeStruct(
+            (T, B, H), jnp.float32, vma=varying_axes(xw, u_h, h0))] * 2,
         scratch_shapes=[pltpu.VMEM((bm, H), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="miru_scan",
     )(xw, u_h, h0)
     return h_all, pre

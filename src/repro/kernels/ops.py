@@ -19,7 +19,7 @@ from repro.kernels.kwta import kwta_pallas
 from repro.kernels.miru_scan import miru_scan_pallas
 from repro.kernels.wbs_matmul import wbs_matmul_pallas
 from repro.kernels.wbs_miru_scan import wbs_miru_scan_pallas
-from repro.utils import round_up
+from repro.utils import round_up, zeros_like_varying
 
 
 def _interpret() -> bool:
@@ -139,22 +139,51 @@ def device_vmm(x: jax.Array, w: jax.Array, backend="wbs",
 # MiRU fused recurrence
 # ---------------------------------------------------------------------------
 
+# Batch tile of the recurrence kernels: one f32 sublane group. Smaller
+# batches (the serve path's few slots) pad up to it.
+_SCAN_BM = 8
+
+# VMEM budget for the double-buffered time-chunk blocks a recurrence
+# kernel streams: each step of a chunk moves one (bm, Hp) f32 row block
+# per streamed array, twice over for double buffering.
+_SCAN_STREAM_BYTES = 4 << 20
+
+
+def _time_chunk(T: int, bm: int, Hp: int, n_streams: int) -> tuple[int, int]:
+    """(tc, Tp): the steps per grid cell and the padded sequence length.
+    Chunks are as long as the stream budget allows and balanced, so the
+    causal tail padding (Tp − T steps, computed then sliced off) stays
+    below one step per chunk."""
+    cap = max(1, _SCAN_STREAM_BYTES // (2 * n_streams * bm * Hp * 4))
+    n_chunks = -(-T // cap)
+    tc = -(-T // n_chunks)
+    return tc, tc * n_chunks
+
+
+def _to_time_major(x: jax.Array, Tp: int, Bp: int, Hp: int) -> jax.Array:
+    """(B, T, H) → zero-padded time-major (Tp, Bp, Hp)."""
+    B, T, H = x.shape
+    return jnp.pad(jnp.swapaxes(x, 0, 1),
+                   ((0, Tp - T), (0, Bp - B), (0, Hp - H)))
+
+
+def _from_time_major(x: jax.Array, T: int, B: int, H: int) -> jax.Array:
+    return jnp.swapaxes(x[:T, :B, :H], 0, 1)
+
+
 def miru_scan(xw: jax.Array, u_h: jax.Array, h0: jax.Array, beta: float,
               lam: float) -> tuple[jax.Array, jax.Array]:
     """Fused MiRU recurrence. xw (B,T,H), u_h (H,H), h0 (B,H)."""
     B, T, H = xw.shape
-    bm = 8 if B >= 8 else B
-    Bp = round_up(B, bm)
-    Hp = round_up(H, 128)
-    if Bp != B or Hp != H:
-        xw_p = jnp.pad(xw, ((0, Bp - B), (0, 0), (0, Hp - H)))
-        u_p = jnp.pad(u_h, ((0, Hp - H), (0, Hp - H)))
-        h0_p = jnp.pad(h0, ((0, Bp - B), (0, Hp - H)))
-    else:
-        xw_p, u_p, h0_p = xw, u_h, h0
-    h_all, pre = miru_scan_pallas(xw_p, u_p, h0_p, beta=beta, lam=lam,
-                                  bm=bm, interpret=_interpret())
-    return h_all[:B, :, :H], pre[:B, :, :H]
+    Bp, Hp = round_up(B, _SCAN_BM), round_up(H, 128)
+    tc, Tp = _time_chunk(T, _SCAN_BM, Hp, n_streams=3)
+    h_all, pre = miru_scan_pallas(
+        _to_time_major(xw, Tp, Bp, Hp),
+        jnp.pad(u_h, ((0, Hp - H), (0, Hp - H))),
+        jnp.pad(h0, ((0, Bp - B), (0, Hp - H))),
+        beta=beta, lam=lam, bm=_SCAN_BM, tc=tc, interpret=_interpret())
+    return (_from_time_major(h_all, T, B, H),
+            _from_time_major(pre, T, B, H))
 
 
 # ---------------------------------------------------------------------------
@@ -200,7 +229,8 @@ def wbs_input_drive(x_seq: jax.Array, w_h: jax.Array, n_bits: int,
         # (dyadic), the same collapse XLA applies to the per-step einsum.
         top = float(2 ** n_bits - 1)
         deq = jnp.clip(jnp.round(x2 * top), -top, top) * (2.0 ** -n_bits)
-        y = jnp.dot(deq, w, preferred_element_type=jnp.float32) * norm
+        y = jnp.dot(deq, w, preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST) * norm
     else:
         # Per-step plane gains: accumulate the gain-weighted bit planes
         # one plane at a time — MSB first, the same reduction order as
@@ -217,7 +247,8 @@ def wbs_input_drive(x_seq: jax.Array, w_h: jax.Array, n_bits: int,
             deq = deq + g[None, :, b, None] * plane
         deq = deq * sign.astype(jnp.float32)
         y = jnp.dot(deq.reshape(B * T, K), w,
-                    preferred_element_type=jnp.float32) * norm
+                    preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST) * norm
     return (y * weight_scale).reshape(B, T, w.shape[-1])
 
 
@@ -228,24 +259,25 @@ def _wbs_miru_scan_primal(static: _FusedStatic, drive, u_h, h0, b_h,
         else not _interpret()
     u_scaled = (u_h / static.weight_scale).astype(jnp.float32)
     if use_kernel and round_up(H, 128) <= _FUSED_H_LIMIT:
-        bm = 8 if B >= 8 else B
-        Bp, Hp = round_up(B, bm), round_up(H, 128)
-        drive_p = jnp.pad(drive, ((0, Bp - B), (0, 0), (0, Hp - H)))
-        u_p = jnp.pad(u_scaled, ((0, Hp - H), (0, Hp - H)))
-        h0_p = jnp.pad(h0, ((0, Bp - B), (0, Hp - H)))
-        b_p = jnp.pad(b_h.reshape(1, H), ((0, 0), (0, Hp - H)))
+        Bp, Hp = round_up(B, _SCAN_BM), round_up(H, 128)
+        tc, Tp = _time_chunk(T, _SCAN_BM, Hp, n_streams=4)
         if gains is None:
             g = 2.0 ** (-jnp.arange(1, static.n_bits + 1,
                                     dtype=jnp.float32))
-            gains_p = jnp.tile(g[None, :], (T, 1))
+            gains_p = jnp.tile(g, Tp)
         else:
-            gains_p = gains.astype(jnp.float32)
-        h_all, h_prev, pre = wbs_miru_scan_pallas(
-            drive_p, u_p, h0_p, b_p, gains_p, beta=static.beta,
-            lam=static.lam, n_bits=static.n_bits,
+            gains_p = jnp.pad(gains.astype(jnp.float32),
+                              ((0, Tp - T), (0, 0))).reshape(-1)
+        outs = wbs_miru_scan_pallas(
+            _to_time_major(drive, Tp, Bp, Hp),
+            jnp.pad(u_scaled, ((0, Hp - H), (0, Hp - H))),
+            jnp.pad(h0, ((0, Bp - B), (0, Hp - H))),
+            jnp.pad(b_h.reshape(1, H), ((0, 0), (0, Hp - H))),
+            gains_p, beta=static.beta, lam=static.lam, n_bits=static.n_bits,
             adc_bits=static.adc_bits, adc_range=static.adc_range,
-            w_scale=static.weight_scale, bm=bm, interpret=_interpret())
-        return (h_all[:B, :, :H], h_prev[:B, :, :H], pre[:B, :, :H])
+            w_scale=static.weight_scale, bm=_SCAN_BM, tc=tc,
+            interpret=_interpret())
+        return tuple(_from_time_major(o, T, B, H) for o in outs)
     return ref.wbs_miru_scan_ref(
         drive, u_scaled, h0, b_h.reshape(1, H), beta=static.beta,
         lam=static.lam, n_bits=static.n_bits, adc_bits=static.adc_bits,
@@ -325,7 +357,7 @@ def wbs_miru_scan(drive: jax.Array, u_h: jax.Array, b_h: jax.Array,
     """
     B, T, H = drive.shape
     if h0 is None:
-        h0 = jnp.zeros((B, H), jnp.float32)
+        h0 = zeros_like_varying((B, H), jnp.float32, drive, u_h, b_h)
     static = _FusedStatic(beta=float(beta), lam=float(lam), n_bits=n_bits,
                           adc_bits=adc_bits, adc_range=float(adc_range),
                           weight_scale=float(weight_scale),

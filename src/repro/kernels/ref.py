@@ -1,12 +1,16 @@
 """Pure-jnp oracles for every Pallas kernel (the correctness contract).
 
 Each function mirrors its kernel's *exact* integer/bit semantics so the
-sweep tests can assert allclose at fp32 tolerance.
+sweep tests can assert allclose at fp32 tolerance. Every contraction is
+pinned to ``Precision.HIGHEST``, as in the kernels: on the TPU an f32
+product at default precision rounds its operands to bf16.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def wbs_matmul_ref(sign: jax.Array, code: jax.Array, w: jax.Array,
@@ -26,7 +30,7 @@ def wbs_matmul_ref(sign: jax.Array, code: jax.Array, w: jax.Array,
     planes = (code[None, :, :] >> ks[:, None, None]) & 1        # (nb, M, K)
     signed = planes.astype(jnp.float32) * sign.astype(jnp.float32)[None]
     y = jnp.einsum("b,bmk,kn->mn", gains.astype(jnp.float32), signed,
-                   w.astype(jnp.float32))
+                   w.astype(jnp.float32), precision=_HIGHEST)
     y = y * (2.0 ** n_bits / (2.0 ** n_bits - 1.0))
     if adc_bits is not None:
         levels = 2 ** adc_bits
@@ -45,7 +49,8 @@ def miru_scan_ref(xw: jax.Array, u_h: jax.Array, h0: jax.Array,
     Returns (h_all (B,T,H), pre (B,T,H)).
     """
     def step(h, xw_t):
-        pre = xw_t + (beta * h) @ u_h.astype(jnp.float32)
+        pre = xw_t + jnp.dot(beta * h, u_h.astype(jnp.float32),
+                             precision=_HIGHEST)
         h_new = lam * h + (1.0 - lam) * jnp.tanh(pre)
         return h_new, (h_new, pre)
 
@@ -92,8 +97,10 @@ def wbs_miru_scan_ref(drive: jax.Array, u_h: jax.Array, h0: jax.Array,
             sign = jnp.sign(bh)
             planes = ((mag.astype(jnp.int32)[None]
                        >> shifts[:, None, None]) & 1).astype(jnp.float32)
-            deq = jnp.einsum("k,kbi->bi", g_t, planes * sign[None])
-        y = jnp.dot(deq, u, preferred_element_type=jnp.float32)
+            deq = jnp.einsum("k,kbi->bi", g_t, planes * sign[None],
+                             precision=_HIGHEST)
+        y = jnp.dot(deq, u, preferred_element_type=jnp.float32,
+                    precision=_HIGHEST)
         y = y * norm * w_scale
         pre = (d_t + y) + b_h[0]
         if adc_bits is not None:

@@ -20,7 +20,8 @@ CPU interpret-mode lowering, so ``ops.wbs_matmul`` applies the jnp
 reference noise model up front on CPU instead (one draw per call).
 
 Block shapes default to 128-aligned tiles (MXU native); the ops.py wrapper
-pads arbitrary shapes.
+pads arbitrary shapes. The plane matmuls run at ``Precision.HIGHEST``
+(f32 weights, f32 accumulate), pinned like the jnp reference's.
 """
 from __future__ import annotations
 
@@ -32,16 +33,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.utils import varying_axes
+
 
 def _uniform_01(shape):
     """Uniform in (0, 1] from the on-chip PRNG (24-bit mantissa).
 
-    ``prng_random_bits`` yields *int32*; bitcast to uint32 before the
-    shift — an arithmetic shift on the signed view would send half of
-    all draws negative (then clamp to 2^-24, wrecking the distribution).
+    ``prng_random_bits`` yields int32. A *logical* shift by 8 leaves the
+    top 24 bits as a non-negative int32 (an arithmetic shift would send
+    half of all draws negative), which converts to f32 exactly — the
+    chip has no unsigned-to-float conversion.
     """
-    bits = pltpu.bitcast(pltpu.prng_random_bits(shape), jnp.uint32)
-    u = (bits >> 8).astype(jnp.float32) * (2.0 ** -24)
+    bits = jax.lax.shift_right_logical(pltpu.prng_random_bits(shape), 8)
+    u = bits.astype(jnp.float32) * (2.0 ** -24)
     return jnp.maximum(u, 2.0 ** -24)
 
 
@@ -59,7 +63,9 @@ def _wbs_kernel(sign_ref, code_ref, w_ref, gains_ref, *refs,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     sign = sign_ref[...].astype(jnp.float32)
-    code = code_ref[...]
+    # Widen the uint8 codes before the plane extraction: the chip has no
+    # uint8→f32 conversion, and the int32 planes are the same bits.
+    code = code_ref[...].astype(jnp.int32)
     w = w_ref[...].astype(jnp.float32)
 
     if read_sigma > 0:
@@ -80,7 +86,8 @@ def _wbs_kernel(sign_ref, code_ref, w_ref, gains_ref, *refs,
         shift = n_bits - 1 - b                      # MSB first (k=1 ⇒ 2^-1)
         plane = ((code >> shift) & 1).astype(jnp.float32) * sign
         acc = acc + gains_ref[0, b] * jnp.dot(
-            plane, w, preferred_element_type=jnp.float32)
+            plane, w, preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST)
     acc_ref[...] = acc
 
     @pl.when(k == n_k - 1)
@@ -138,7 +145,9 @@ def wbs_matmul_pallas(sign: jax.Array, code: jax.Array, w: jax.Array,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32,
+                                       vma=varying_axes(*operands)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="wbs_matmul",
     )(*operands)

@@ -136,7 +136,6 @@ def _local_dispatch(xt, probs, E: int, K: int, C: int):
 def _moe_ffn_ep(p: dict, cfg: ModelConfig, x: jax.Array, ctx
                 ) -> jax.Array:
     """Expert-parallel dispatch under shard_map (see moe_ffn)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = ctx.mesh
@@ -201,11 +200,11 @@ def _moe_ffn_ep(p: dict, cfg: ModelConfig, x: jax.Array, ctx
     else:
         wg_spec = P(None, None, None)
         wd_spec = P(None, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         block, mesh=mesh,
         in_specs=(x_spec, P(None, None), wg_spec, wg_spec, wd_spec),
         out_specs=x_spec,
-        check_rep=False)
+        check_vma=False)
     return fn(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
 

@@ -63,6 +63,7 @@ from repro.replay import get_policy_class, ingraph_init
 from repro.data.synthetic import TaskData
 from repro.scenarios.metrics import continual_metrics
 from repro.scenarios.registry import get_scenario
+from repro.utils import zeros_like_varying
 
 __all__ = ["run_compiled", "run_sweep", "scenario_miru_config"]
 
@@ -232,7 +233,7 @@ def _make_run_fn(cfg, trainer: TrainerSpec, backend: DeviceBackend,
             accs = eval_all(p, k_eval, d)
             return carry, (accs, step_ys)
 
-        wc0 = {n: jnp.zeros(p.shape, jnp.int32)
+        wc0 = {n: zeros_like_varying(p.shape, jnp.int32, params, xs)
                for n, p in params.items()
                if jnp.ndim(p) >= 2} if track_writes else None
         replay_on = jnp.arange(n_tasks) > 0
@@ -329,7 +330,7 @@ def _make_masked_run_fn(cfg, trainer: TrainerSpec, backend: DeviceBackend,
             accs = eval_all(p, k_eval, d, n_tasks * n_tasks)
             return carry, (accs, losses_t)
 
-        wc0 = {n: jnp.zeros(p.shape, jnp.int32)
+        wc0 = {n: zeros_like_varying(p.shape, jnp.int32, params, xs)
                for n, p in params.items()
                if jnp.ndim(p) >= 2} if track_writes else None
         with tele.deferred():
@@ -444,8 +445,10 @@ def run_compiled(cfg, spec: TrainerSpec, tasks: list[TaskData],
     as ``"runlog"`` (with a leading per-seed axis under ``seeds``), and
     a tracer records ``schedule`` / ``compile`` / ``execute`` spans —
     compile separated from execute by lowering ahead of time, which is
-    also what ``"compile_s"``/``"execute_s"`` report. ``obs=None`` (the
-    default) compiles and runs the exact pre-obs program.
+    also what ``"compile_s"``/``"execute_s"`` report; the compiled
+    program itself comes back as ``"executable"`` (a
+    ``jax.stages.Compiled``, for inspecting what it runs). ``obs=None``
+    (the default) compiles and runs the exact pre-obs program.
 
     ``pad`` is a :class:`repro.data.ragged.PadPolicy`: ragged streams
     (unequal n_train/n_test/sequence length across tasks) pad onto one
@@ -637,6 +640,7 @@ def run_compiled(cfg, spec: TrainerSpec, tasks: list[TaskData],
     if compile_s is not None:
         out["compile_s"] = compile_s
         out["execute_s"] = execute_s
+        out["executable"] = compiled_fn
     if obs_on:
         from repro.obs.runlog import build_runlog, drift_stream
 

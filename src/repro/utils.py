@@ -6,6 +6,8 @@ import it without cycles.
 from __future__ import annotations
 
 import functools
+import os
+from pathlib import Path
 from typing import Any, Callable, Mapping
 
 import jax
@@ -37,6 +39,29 @@ def normal_init(key: jax.Array, shape: tuple[int, ...], stddev: float,
 def truncated_normal_init(key: jax.Array, shape: tuple[int, ...],
                           stddev: float, dtype=jnp.float32) -> jax.Array:
     return stddev * jax.random.truncated_normal(key, -2.0, 2.0, shape, dtype)
+
+
+def varying_axes(*like: Any) -> frozenset:
+    """The manual mesh axes any leaf of ``like`` varies over — empty
+    outside ``shard_map``. Kernel output shapes carry it (``pallas_call``
+    under ``shard_map`` must be told how its outputs vary)."""
+    axes = frozenset()
+    for leaf in jax.tree.leaves(like):
+        axes |= jax.typeof(leaf).vma
+    return axes
+
+
+def zeros_like_varying(shape: tuple[int, ...], dtype, *like: Any
+                       ) -> jax.Array:
+    """Zeros of ``shape`` that vary over every manual mesh axis any leaf
+    of ``like`` varies over. Under ``shard_map`` a scan carry seeded from
+    plain zeros is mesh-invariant while the carry the body returns varies
+    with the shard's data or weights, and ``lax.scan`` refuses the
+    mismatch; outside ``shard_map`` this is plain ``jnp.zeros``."""
+    z = jnp.zeros(shape, dtype)
+    axes = varying_axes(*like)
+    return jax.lax.pcast(z, tuple(sorted(axes)), to="varying") \
+        if axes else z
 
 
 # ---------------------------------------------------------------------------
@@ -152,3 +177,24 @@ def count_params(params: PyTree) -> int:
 @functools.lru_cache(maxsize=None)
 def cpu_device():
     return jax.devices("cpu")[0]
+
+
+# ---------------------------------------------------------------------------
+# Compilation cache (entry points only)
+# ---------------------------------------------------------------------------
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for a program entry
+    point (scripts, examples, benchmarks — never at library import).
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps its
+    cache there and nothing is set here. Otherwise the cache goes to
+    ``.jax_cache/`` at the root of this checkout: a fixed path, because
+    the path is part of each entry's key and a moving directory never
+    hits. Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(Path(__file__).resolve().parents[2] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

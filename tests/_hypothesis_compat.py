@@ -1,105 +1,14 @@
-"""Optional-hypothesis shim for the property-based tests.
+"""Shared hypothesis entry point for the property-based tests.
 
-hypothesis is a test-only dependency (pip install .[test]). Where it is
-absent the suite must still *run* the property tests, so this module
-provides a miniature deterministic property runner with the same calling
-convention: ``@settings(max_examples=N) @given(st.integers(...), ...)``.
-The fallback draws a fixed number of pseudo-random examples per test
-(seeded from the test name — reproducible across runs and processes),
-always including the strategy bounds first, and supports the strategy
-subset the suite uses: ``integers``, ``floats``, ``sampled_from``,
-``booleans`` and ``data()`` (with ``data.draw``). With hypothesis
-installed, the real library (shrinking, edge-case database) is used
-unchanged. Import ``given``, ``settings``, ``st`` from here instead of
-from hypothesis directly.
+Import ``given``, ``settings``, ``st`` from here instead of from
+hypothesis directly. Loading this module installs the suite's settings
+profile: no per-example deadline, because the first example of a
+property test pays JAX's trace-and-compile time, which has nothing to do
+with the property under test.
 """
-import functools
-import zlib
+from hypothesis import given, settings, strategies as st
 
-import numpy as np
+settings.register_profile("repro", deadline=None)
+settings.load_profile("repro")
 
-try:
-    from hypothesis import given, settings, strategies as st
-    HAS_HYPOTHESIS = True
-except ImportError:
-    HAS_HYPOTHESIS = False
-
-    class _Strategy:
-        """A draw function plus the boundary examples tried first."""
-
-        def __init__(self, draw, edges=()):
-            self._draw = draw
-            self.edges = tuple(edges)
-
-        def draw(self, rng, example_idx):
-            if example_idx < len(self.edges):
-                return self.edges[example_idx]
-            return self._draw(rng)
-
-    class _DataMarker:
-        """Stands in for ``st.data()``."""
-
-    class _Data:
-        def __init__(self, rng):
-            self._rng = rng
-
-        def draw(self, strategy):
-            return strategy.draw(self._rng, len(strategy.edges))
-
-    class _St:
-        @staticmethod
-        def integers(min_value, max_value):
-            return _Strategy(
-                lambda r: int(r.integers(min_value, max_value + 1)),
-                edges=(min_value, max_value))
-
-        @staticmethod
-        def floats(min_value, max_value, **_kw):
-            return _Strategy(
-                lambda r: float(r.uniform(min_value, max_value)),
-                edges=(min_value, max_value))
-
-        @staticmethod
-        def sampled_from(seq):
-            seq = list(seq)
-            return _Strategy(lambda r: seq[int(r.integers(len(seq)))],
-                             edges=(seq[0],))
-
-        @staticmethod
-        def booleans():
-            return _Strategy(lambda r: bool(r.integers(2)),
-                             edges=(False, True))
-
-        @staticmethod
-        def data():
-            return _DataMarker()
-
-    st = _St()
-
-    def given(*strategies, **kw_strategies):
-        def deco(fn):
-            @functools.wraps(fn)
-            def wrapper(*args, **kwargs):
-                n = getattr(wrapper, "_max_examples", 12)
-                base = zlib.crc32(fn.__qualname__.encode())
-                for i in range(n):
-                    rng = np.random.default_rng(
-                        np.random.SeedSequence([base, i]))
-                    pos = [(_Data(rng) if isinstance(s, _DataMarker)
-                            else s.draw(rng, i)) for s in strategies]
-                    kw = {k: (_Data(rng) if isinstance(s, _DataMarker)
-                              else s.draw(rng, i))
-                          for k, s in kw_strategies.items()}
-                    fn(*args, *pos, **kwargs, **kw)
-            # pytest follows __wrapped__ to the original signature and
-            # would treat the strategy parameters as fixtures; the
-            # wrapper's own (*args, **kwargs) signature requests none.
-            del wrapper.__wrapped__
-            return wrapper
-        return deco
-
-    def settings(max_examples: int = 12, **_kw):
-        def deco(fn):
-            fn._max_examples = max_examples
-            return fn
-        return deco
+__all__ = ["given", "settings", "st"]

@@ -1,7 +1,6 @@
 """Property-based tests for the fault-mask algebra (repro.faults).
 
-Via tests/_hypothesis_compat.py (real hypothesis when installed, the
-deterministic mini-runner otherwise):
+Via tests/_hypothesis_compat.py (hypothesis with the suite's profile):
 
   * mask application is idempotent — ``apply_cell_faults`` is a
     projection, so read-side and prepare-side masking compose without

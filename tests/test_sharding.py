@@ -10,7 +10,7 @@ from repro.distributed.sharding import (batch_specs, cache_specs,
                                         opt_state_specs, param_specs)
 from repro.models import lm
 
-MESH = AbstractMesh((("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
 
 
 def _spec_of(tree, *path):
