@@ -2,13 +2,11 @@
 from __future__ import annotations
 
 import json
-import sys
 import time
 from pathlib import Path
 
 RESULTS = Path(__file__).resolve().parent / "results"
 RESULTS.mkdir(parents=True, exist_ok=True)
-HISTORY = RESULTS / "history"
 
 
 def time_call(fn, *args, warmup: int = 1, iters: int = 5) -> float:
@@ -33,21 +31,3 @@ def save_json(name: str, payload) -> Path:
     p = RESULTS / f"{name}.json"
     p.write_text(json.dumps(payload, indent=1, default=float))
     return p
-
-
-def append_history(name: str, metrics: dict, *,
-                   gates: dict | None = None,
-                   extra: dict | None = None) -> Path | None:
-    """Append a schema-versioned run record (repro.obs.sinks) to
-    ``results/history/<name>.jsonl`` — the per-commit perf trajectory
-    behind the point-in-time ``BENCH_*.json`` gates. Returns the path,
-    or None when ``repro.obs`` is not importable (benchmarks stay
-    runnable from a partial checkout)."""
-    try:
-        from repro.obs import JsonlSink, run_record
-    except ImportError:
-        print(f"# history append skipped for {name}: repro.obs not "
-              f"importable", file=sys.stderr)
-        return None
-    rec = run_record("bench", name, metrics, gates=gates, extra=extra)
-    return JsonlSink(HISTORY / f"{name}.jsonl").emit(rec)

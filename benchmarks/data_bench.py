@@ -30,7 +30,7 @@ from pathlib import Path
 
 import numpy as np
 
-from benchmarks.common import append_history, emit, save_json
+from benchmarks.common import emit, save_json
 
 SEQ_MNIST = dict(n_tasks=3, n_train=192, n_test=96)
 
@@ -222,14 +222,6 @@ def main() -> int:
         Path("BENCH_data.json").write_text(
             json.dumps(out, indent=1, default=float))
         print("wrote BENCH_data.json")
-        append_history(
-            "data_bench",
-            {"seq_mnist_ideal_MA": out["seq_mnist"]["ideal"]["MA"],
-             "seq_mnist_wbs_MA": out["seq_mnist"]["wbs"]["MA"],
-             "seq_mnist_acc_gap": out["seq_mnist"]["acc_gap"],
-             "masked_wall_s": out["masked_overhead"]["masked"],
-             "unmasked_wall_s": out["masked_overhead"]["unmasked"]},
-            gates=out["gates"])
         ok = all(out["gates"].values())
         if not ok:
             print(f"GATE FAILURE: {out['gates']}")

@@ -36,7 +36,7 @@ from pathlib import Path
 
 import numpy as np
 
-from benchmarks.common import append_history, emit, save_json
+from benchmarks.common import emit, save_json
 
 #: Stuck-cell rates for the degradation curve (total; half SA0, half
 #: SA1 — SA1 cells read full range with random sign, the damaging end).
@@ -350,16 +350,6 @@ def main() -> int:
         Path("BENCH_faults.json").write_text(
             json.dumps(out, indent=1, default=float))
         print("wrote BENCH_faults.json")
-        mit = out["degradation"]["mitigation"]
-        append_history(
-            "fault_bench",
-            {"clean_accuracy": out["degradation"]["clean_accuracy"],
-             "faulty_1pct": mit["faulty_accuracy"],
-             "mitigated_1pct": mit["mitigated_accuracy"],
-             "wearout_onset_years": out["wearout"]["onset_age_years"],
-             "wearout_projected_years":
-                 out["wearout"]["projected_lifespan_years"]},
-            gates=out["gates"])
         ok = all(out["gates"].values())
         if not ok:
             print(f"GATE FAILURE: {out['gates']}")

@@ -39,7 +39,7 @@ from pathlib import Path
 
 import numpy as np
 
-from benchmarks.common import append_history, emit, save_json
+from benchmarks.common import emit, save_json
 
 FLEET_DEVICES = 8
 #: Minimum acceptable t_vmap / t_fleet (sharding-overhead bound).
@@ -164,12 +164,6 @@ def main() -> int:
     Path("BENCH_fleet.json").write_text(
         json.dumps(out, indent=1, default=float))
     print("wrote BENCH_fleet.json")
-    if args.gate:
-        append_history(
-            "fleet_bench",
-            {"scaling_efficiency": out["scaling"]["scaling_efficiency"],
-             "t_fleet_s": out["scaling"]["t_fleet_s"]},
-            gates=out["gates"])
     ok = all(out["gates"].values())
     if not ok:
         print(f"GATE FAILURE: {out['gates']}")

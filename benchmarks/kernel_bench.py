@@ -28,7 +28,7 @@ import numpy as np
 
 from repro.kernels import ops, ref
 
-from benchmarks.common import append_history, emit, save_json, time_call
+from benchmarks.common import emit, save_json, time_call
 
 # The paper's Fig. 4 geometry: 28 features × 100 hidden × 10 classes,
 # T=28 time steps (row-serial MNIST), batch 32.
@@ -218,12 +218,6 @@ def main() -> int:
         Path("BENCH_kernels.json").write_text(
             json.dumps(out, indent=1, default=float))
         print("wrote BENCH_kernels.json")
-        append_history(
-            "kernel_bench",
-            {"fused_speedup": out["fused_recurrence"]["speedup"],
-             "per_step_us": out["fused_recurrence"]["per_step"]["us"],
-             "fused_us": out["fused_recurrence"]["fused"]["us"]},
-            gates=out["gates"])
         ok = all(out["gates"].values())
         if not ok:
             print(f"GATE FAILURE: {out['gates']}")

@@ -31,7 +31,7 @@ from pathlib import Path
 import jax
 import numpy as np
 
-from benchmarks.common import append_history, emit, save_json
+from benchmarks.common import emit, save_json
 
 # Paper geometry: 28 features × 100 hidden × 10 classes, T=28, batch 32.
 N_H = 100
@@ -191,12 +191,6 @@ def main() -> int:
         Path("BENCH_obs.json").write_text(
             json.dumps(out, indent=1, default=float))
         print("wrote BENCH_obs.json")
-        append_history(
-            "obs_bench",
-            {"overhead_pct": out["overhead"]["overhead_pct"],
-             "execute_disabled_s": out["overhead"]["disabled"]["execute_s"],
-             "execute_enabled_s": out["overhead"]["enabled"]["execute_s"]},
-            gates=out["gates"])
         ok = all(out["gates"].values())
         if not ok:
             print(f"GATE FAILURE: {out['gates']}")

@@ -8,9 +8,8 @@ Two modes:
 * ``python -m benchmarks.run --gate`` — the unified gate runner: every
   ``benchmarks/*_bench.py`` that supports ``--gate`` runs in its own
   subprocess (a crashed bench can't take down the others), their
-  ``BENCH_*.json`` artifacts merge into ``BENCH_all.json``, one run
-  record lands in ``results/history/bench_all.jsonl``, and the exit
-  code is nonzero if any gate failed. A ``*_bench.py`` without a
+  ``BENCH_*.json`` artifacts merge into ``BENCH_all.json``, and the
+  exit code is nonzero if any gate failed. A ``*_bench.py`` without a
   ``--gate`` flag (argparse exit code 2) is reported as skipped, not
   failed. CI runs this one entry point instead of one job per bench.
 """
@@ -23,8 +22,6 @@ import subprocess
 import sys
 import time
 from pathlib import Path
-
-from benchmarks.common import append_history
 
 
 def smoke() -> None:
@@ -112,12 +109,6 @@ def main() -> int:
     Path("BENCH_all.json").write_text(
         json.dumps(merged, indent=1, default=float))
     print("wrote BENCH_all.json")
-    append_history(
-        "bench_all",
-        {"wall_s": merged["wall_s"],
-         "statuses": {k: v["status"]
-                      for k, v in merged["benches"].items()}},
-        gates=merged["gates"])
     if not merged["ok"]:
         failed = [k for k, v in merged["gates"].items() if not v] + \
             [k for k, v in merged["benches"].items()

@@ -38,7 +38,7 @@ from repro.replay import available_policies
 from repro.scenarios import (build_scenario, run_compiled, run_sweep,
                              scenario_miru_config)
 
-from benchmarks.common import append_history, emit, save_json
+from benchmarks.common import emit, save_json
 
 # The policy-column workload: class-incremental with a 3× per-task
 # stream growth (imbalance), where frequency-weighted rehearsal lets
@@ -206,12 +206,6 @@ def main() -> int:
     Path("BENCH_scenarios.json").write_text(
         json.dumps(out, indent=1, default=float))
     print("wrote BENCH_scenarios.json")
-    append_history(
-        "scenarios_grid",
-        {"speedup": out["speedup"]["speedup"],
-         "compiled_s": out["speedup"]["compiled_s"],
-         "grid_seconds": out["grid_seconds"]},
-        gates=out["gates"])
     ok = all(out["gates"].values())
     if not ok:
         print(f"GATE FAILURE: {out['gates']}")

@@ -36,7 +36,7 @@ from pathlib import Path
 
 import numpy as np
 
-from benchmarks.common import append_history, emit, save_json
+from benchmarks.common import emit, save_json
 
 # Paper geometry: 28 features × 100 hidden × 10 classes, n_T = 28.
 N_X, N_H, N_Y = 28, 100, 10
@@ -296,14 +296,6 @@ def main() -> int:
         Path("BENCH_serve.json").write_text(
             json.dumps(out, indent=1, default=float))
         print("wrote BENCH_serve.json")
-        append_history(
-            "serve_bench",
-            {"speedup": out["throughput"]["speedup"],
-             "seq_per_s_64": out["throughput"]["wbs"]["loaded_64"]
-             ["sequences_per_s"],
-             "poisson_p99_ms": out["poisson"]["latency_ms"]["p99"],
-             "power_mw": out["energy"]["power_mw"]},
-            gates=out["gates"])
         ok = all(out["gates"].values())
         if not ok:
             print(f"GATE FAILURE: {out['gates']}")

@@ -30,7 +30,7 @@ from repro.core.miru import MiRUConfig, init_miru_params, miru_forward
 from repro.data.synthetic import make_permuted_tasks
 from repro.telemetry import cmos_comparison, telemetry_report
 
-from benchmarks.common import append_history, emit, save_json, time_call
+from benchmarks.common import emit, save_json, time_call
 
 
 def metered_run(backend_name: str, fast: bool) -> tuple:
@@ -123,13 +123,6 @@ def main() -> int:
         Path("BENCH_table1.json").write_text(
             json.dumps(out, indent=1, default=float))
         print("wrote BENCH_table1.json")
-        append_history(
-            "table1_throughput",
-            {"power_mw": out["metered"]["power_mw"],
-             "gops_per_w": out["metered"]["gops_per_w"],
-             "pj_per_op": out["metered"]["pj_per_op"],
-             "agreement": out["agreement"]},
-            gates={"within_5pct": out["within_5pct"]})
     return 0 if out["within_5pct"] else 1
 
 

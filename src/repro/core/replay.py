@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.tracer import span
+
 
 # ---------------------------------------------------------------------------
 # Xorshift32 — the paper's RNG (chosen over LFSR for unbiased indices)
@@ -292,8 +294,10 @@ class ReplayBuffer:
         slot = self.policy.select_insert(int(y), int(task_id))
         if slot is None:
             return False
-        self._qkey, sub = jax.random.split(self._qkey)
-        q = np.asarray(stochastic_quantize(jnp.asarray(x), sub, self.n_bits))
+        with span("replay.quantize"):
+            self._qkey, sub = jax.random.split(self._qkey)
+            q = np.asarray(stochastic_quantize(jnp.asarray(x), sub,
+                                               self.n_bits))
         self._feat[slot] = q
         self._label[slot] = y
         self.size = self.policy.occupancy
@@ -327,9 +331,11 @@ class ReplayBuffer:
             return 0
         # The exact sequential key chain self._qkey would have walked,
         # computed in one scan dispatch; then one vmapped quantize.
-        self._qkey, subs = _split_chain(self._qkey, len(slots))
-        q = np.asarray(_quantize_many(
-            jnp.asarray(np.ascontiguousarray(xs[keep])), subs, self.n_bits))
+        with span("replay.quantize"):
+            self._qkey, subs = _split_chain(self._qkey, len(slots))
+            q = np.asarray(_quantize_many(
+                jnp.asarray(np.ascontiguousarray(xs[keep])), subs,
+                self.n_bits))
         for slot, qi, i in zip(slots, q, keep):
             self._feat[slot] = qi
             self._label[slot] = int(ys[i])

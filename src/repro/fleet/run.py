@@ -29,10 +29,8 @@ tests/test_fleet.py and benchmarks/fleet_bench.py).
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import math
-import time
 from typing import Any, Optional, Union
 
 import jax
@@ -50,9 +48,11 @@ from repro.fleet.heterogeneity import (FleetSpec, device_seeds,
                                        draw_heterogeneity,
                                        overlay_device_states,
                                        overlay_fault_states)
+from repro.obs.tracer import activate, span
 from repro.replay import get_policy_class
 from repro.scenarios.sweep import (_aggregate_seeds, _build_seed_inputs,
-                                   _make_run_fn, _summarize_run)
+                                   _compile_and_run, _make_run_fn,
+                                   _summarize_run)
 
 __all__ = ["run_fleet", "fleet_shard_count"]
 
@@ -139,10 +139,8 @@ def run_fleet(cfg, spec: TrainerSpec, tasks: list[TaskData],
                          "(one trace serves the whole fleet)")
 
     _, _, opt = _make_raw_steps(cfg, trainer, backend)
-    sched_scope = tracer.span("schedule", n_devices=D) \
-        if tracer is not None else contextlib.nullcontext()
     inputs, scheds = [], []
-    with sched_scope:
+    with activate(tracer), span("schedule", n_devices=D):
         for d, s in enumerate(seeds):
             tsp = dataclasses.replace(trainer, seed=int(s))
             # Per-chip data shard: disjoint strided training slices of
@@ -222,28 +220,10 @@ def run_fleet(cfg, spec: TrainerSpec, tasks: list[TaskData],
                                in_specs=(ax,) * 8 + (P(), P()),
                                out_specs=ax),
                  donate_argnums=(0, 2))
-    t0 = time.perf_counter()
-    compile_s = execute_s = None
-    if tracer is not None:
-        # AOT lowering separates compile from execute; the telemetry
-        # scale scope wraps the lowering — that is when the per-shard
-        # deltas are recorded.
-        with tracer.span("compile", backend=backend.name, n_devices=D,
-                         n_shards=n_shards):
-            with tele.scaled(n_local):
-                lowered = fn.lower(*stacked, eval_x, eval_y)
-            compiled_fn = lowered.compile()
-        compile_s = time.perf_counter() - t0
-        t1 = time.perf_counter()
-        with tracer.span("execute", backend=backend.name, n_devices=D):
-            res, shard_devices = _fetch(compiled_fn(*stacked, eval_x,
-                                                    eval_y))
-        execute_s = time.perf_counter() - t1
-    else:
-        with tele.scaled(n_local):
-            res = fn(*stacked, eval_x, eval_y)
-        res, shard_devices = _fetch(res)
-    wall_s = time.perf_counter() - t0
+    (res, shard_devices), _, times = _compile_and_run(
+        fn, (*stacked, eval_x, eval_y), tracer=tracer,
+        scope=tele.scaled(n_local), fetch=_fetch, backend=backend.name,
+        n_devices=D, n_shards=n_shards)
     obs_streams = res.pop("obs", None)
 
     # Host-side accounting of the scan-summed write pulses — fleet
@@ -273,7 +253,6 @@ def run_fleet(cfg, spec: TrainerSpec, tasks: list[TaskData],
         "n_shards": n_shards,
         "shard_devices": shard_devices,
         "n_local": n_local,
-        "wall_s": wall_s,
         "steps_per_task": S,
         "updates_per_device": n_tasks * S,
         "het": het_np,
@@ -285,9 +264,7 @@ def run_fleet(cfg, spec: TrainerSpec, tasks: list[TaskData],
         out["faults"] = {"spec": fspec,
                          "rate_scale": fault_scale_np,
                          "dead_chips": dead_np}
-    if compile_s is not None:
-        out["compile_s"] = compile_s
-        out["execute_s"] = execute_s
+    out.update(times)
     if obs_on:
         from repro.obs.runlog import build_runlog, drift_stream
 

@@ -1,12 +1,9 @@
 """Event/run-record sinks — JSONL emission with a stable schema.
 
-Every gated benchmark and instrumented example can emit a *run record*:
+An instrumented example can emit a *run record*:
 one JSON object per run with a pinned ``schema`` version, enough
 environment fingerprint to compare runs across commits, and the run's
-headline metrics. Appended to ``benchmarks/results/history/<name>.jsonl``
-(see :func:`benchmarks.common.append_history`) these turn the
-``BENCH_*.json`` point-in-time gates into a queryable perf trajectory —
-``jq`` over the history answers "when did the fused speedup regress".
+headline metrics.
 
 Schema (version 1) — stable keys, additive evolution only:
 

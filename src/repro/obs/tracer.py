@@ -10,10 +10,21 @@ directly in ``chrome://tracing`` / Perfetto. The runners separate
 executable under ``execute`` — without a tracer they keep the ordinary
 dispatch path, so tracing is strictly opt-in.
 
-Spans passed a ``step=`` also enter
-``jax.profiler.StepTraceAnnotation`` where the installed jax provides it,
-so a device-side profiler trace captured around the same region gets the
-step markers lined up with the host spans.
+One tracer at a time is *active* (:meth:`Tracer.activate`), and code in
+any layer opens a span on it through the module-level :func:`span`,
+which is one shared null context when no tracer is active;
+:func:`annotate` opens the profiler annotation alone, for work too
+frequent to record as events. The slot is a
+module global, not a ``contextvar``: spans also open on runtime threads
+(the telemetry ``io_callback``), which do not inherit a context. While a
+tracer is active, a ``jax.monitoring`` listener also records each
+backend compile as ``compile.xla``, or ``compile.cache_load`` where the
+persistent cache answered it.
+
+Every span also enters ``jax.profiler.TraceAnnotation`` (a
+``StepTraceAnnotation`` where given ``step=``), so under
+``jax.profiler.start_trace`` the program's spans sit on the profiler's
+host plane, on the same clock as the device ops.
 
 Everything is wall-clock host timing (``time.perf_counter_ns``), threads
 separated by ``tid``; nesting inside a thread is expressed the Chrome
@@ -30,18 +41,107 @@ import time
 from pathlib import Path
 from typing import Optional
 
-__all__ = ["Tracer"]
+import jax
+
+__all__ = ["Tracer", "activate", "annotate", "span"]
+
+# The active tracer (see the module docstring), and the null context
+# every span opens while there is none.
+_active: Optional["Tracer"] = None
+_NULL = contextlib.nullcontext()
+
+# jax.monitoring events of a backend compile request, and of its answer
+# from the persistent cache (jax/_src/dispatch.py, compiler.py).
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_listening = False
+_last_hit = threading.local()
+
+
+def span(name: str, **args):
+    """A span on the active tracer, or a shared null context."""
+    tracer = _active
+    return tracer.span(name, **args) if tracer is not None else _NULL
+
+
+def annotate(name: str):
+    """A profiler annotation, and no tracer event, while a tracer is
+    active; a shared null context otherwise. For work too frequent to
+    record as spans: it still shows on the profiler's timeline."""
+    return jax.profiler.TraceAnnotation(name) if _active is not None \
+        else _NULL
+
+
+def activate(tracer: Optional["Tracer"]):
+    """``tracer.activate()``, or a null context that leaves the active
+    tracer as it is when ``tracer`` is None."""
+    return tracer.activate() if tracer is not None else _NULL
+
+
+def _on_event(event: str, **_kw) -> None:
+    if event == _CACHE_HIT and _active is not None:
+        _last_hit.t = time.time()
+
+
+def _on_time_span(event: str, start: float, end: float, **kw) -> None:
+    tracer = _active
+    if event != _BACKEND_COMPILE or tracer is None:
+        return
+    hit = getattr(_last_hit, "t", None)
+    _last_hit.t = None
+    name = "compile.cache_load" if hit is not None and start <= hit <= end \
+        else "compile.xla"
+    # jax times the request on time.time(); the tracer's clock is
+    # perf_counter.
+    shift = time.perf_counter() - time.time()
+    tracer.record(name, start + shift, end - start, **kw)
+
+
+def _listen_to_compiles() -> None:
+    global _listening
+    if _listening:
+        return
+    jax.monitoring.register_event_listener(_on_event)
+    jax.monitoring.register_event_time_span_listener(_on_time_span)
+    _listening = True
+
+
+class _Span:
+    """One open span: the profiler annotation, then the event on exit (a
+    class, not a generator context manager: it costs less per span)."""
+    __slots__ = ("tracer", "name", "step", "args", "ann", "t0")
+
+    def __init__(self, tracer: "Tracer", name: str, step, args: dict):
+        self.tracer, self.name, self.step, self.args = \
+            tracer, name, step, args
+
+    def __enter__(self):
+        self.ann = jax.profiler.TraceAnnotation(self.name) \
+            if self.step is None \
+            else jax.profiler.StepTraceAnnotation(self.name,
+                                                  step_num=self.step)
+        self.ann.__enter__()
+        self.t0 = time.perf_counter_ns()
+        return self.tracer
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter_ns()
+        self.ann.__exit__(*exc)
+        args = self.args
+        if self.step is not None:
+            args = dict(args, step=self.step)
+        self.tracer._append(self.name, self.t0, t1 - self.t0, args)
+        return False
 
 
 class Tracer:
     """Collects trace events; thread-safe; negligible cost per span
-    (two clock reads and a dict append)."""
+    (two clock reads, a profiler annotation and a list append)."""
 
     def __init__(self, process_name: str = "repro"):
         self._events: list[dict] = []
         self._lock = threading.Lock()
         self._t0 = time.perf_counter_ns()
-        self._depth = threading.local()
         self._events.append({
             "name": "process_name", "ph": "M", "pid": os.getpid(),
             "tid": 0, "args": {"name": process_name}})
@@ -50,43 +150,39 @@ class Tracer:
     def _now_us(self) -> float:
         return (time.perf_counter_ns() - self._t0) / 1e3
 
-    @contextlib.contextmanager
-    def span(self, name: str, step: Optional[int] = None, **args):
-        """Record a nested wall-clock span. ``step`` additionally opens a
-        ``jax.profiler.StepTraceAnnotation`` (ignored where jax lacks
-        it); remaining kwargs land in the event's ``args``."""
-        depth = getattr(self._depth, "n", 0)
-        self._depth.n = depth + 1
-        t0 = self._now_us()
-        ann = contextlib.nullcontext()
-        if step is not None:
-            try:
-                import jax
-                ann = jax.profiler.StepTraceAnnotation(name, step_num=step)
-            except Exception:
-                pass
-        try:
-            with ann:
-                yield self
-        finally:
-            dur = self._now_us() - t0
-            self._depth.n = depth
-            ev_args = dict(args)
-            if step is not None:
-                ev_args["step"] = step
-            with self._lock:
-                self._events.append({
-                    "name": name, "ph": "X", "ts": t0, "dur": dur,
-                    "pid": os.getpid(), "tid": threading.get_ident(),
-                    "args": ev_args, "_depth": depth})
-
-    def instant(self, name: str, **args) -> None:
-        """A zero-duration marker (Chrome ``ph: "i"``)."""
+    def _append(self, name: str, t0_ns: int, dur_ns: int,
+                args: dict) -> None:
         with self._lock:
             self._events.append({
-                "name": name, "ph": "i", "ts": self._now_us(), "s": "t",
-                "pid": os.getpid(), "tid": threading.get_ident(),
-                "args": dict(args)})
+                "name": name, "ph": "X", "ts": (t0_ns - self._t0) / 1e3,
+                "dur": dur_ns / 1e3, "pid": os.getpid(),
+                "tid": threading.get_ident(), "args": args})
+
+    def span(self, name: str, step: Optional[int] = None, **args):
+        """Record a nested wall-clock span, annotated for the profiler
+        (``step`` makes it a ``StepTraceAnnotation``); remaining kwargs
+        land in the event's ``args``."""
+        return _Span(self, name, step, args)
+
+    def record(self, name: str, start_s: float, dur_s: float,
+               **args) -> None:
+        """Record a span known only after it finished: ``start_s`` on
+        ``time.perf_counter``'s clock, ``dur_s`` in seconds."""
+        self._append(name, int(start_s * 1e9), int(dur_s * 1e9),
+                     dict(args))
+
+    @contextlib.contextmanager
+    def activate(self):
+        """Make this the active tracer (the one :func:`span` opens spans
+        on, and that records backend compiles) for the ``with`` block;
+        the previous one is restored on exit."""
+        global _active
+        _listen_to_compiles()
+        prev, _active = _active, self
+        try:
+            yield self
+        finally:
+            _active = prev
 
     def counter(self, name: str, **values) -> None:
         """A counter sample (Chrome ``ph: "C"``) — e.g. queue depth or
@@ -127,10 +223,7 @@ class Tracer:
         """Write the Chrome trace-event JSON. Open in chrome://tracing or
         https://ui.perfetto.dev."""
         path = Path(path)
-        events = []
-        for e in self.events():
-            e.pop("_depth", None)
-            events.append(e)
         path.write_text(json.dumps(
-            {"traceEvents": events, "displayTimeUnit": "ms"}, indent=None))
+            {"traceEvents": self.events(), "displayTimeUnit": "ms"},
+            indent=None))
         return path

@@ -61,6 +61,7 @@ from repro.core.continual import (ReplaySpec, TrainerSpec,
 from repro.core.replay import _split_chain
 from repro.replay import get_policy_class, ingraph_init
 from repro.data.synthetic import TaskData
+from repro.obs.tracer import activate, span
 from repro.scenarios.metrics import continual_metrics
 from repro.scenarios.registry import get_scenario
 from repro.utils import zeros_like_varying
@@ -412,6 +413,38 @@ def _fallback_python(cfg, trainer, tasks, rspec, backend, seeds,
     return out
 
 
+def _compile_and_run(fn, args: tuple, *, tracer, scope, fetch,
+                     **span_args) -> tuple[Any, Any, dict]:
+    """Run the jitted ``fn`` on ``args`` and ``fetch`` its outputs to the
+    host. ``scope`` wraps whatever traces ``fn`` (telemetry records its
+    deltas while tracing). Without a tracer: the ordinary dispatch. With
+    one: lowered ahead of time under the ``compile`` span (a
+    ``compile.lower`` child; the backend compile or cache load records
+    itself), then executed and fetched under ``execute``, so compile
+    time excludes execution.
+
+    Returns the host outputs, the compiled executable (None without a
+    tracer) and the timings: ``wall_s``, plus ``compile_s`` and
+    ``execute_s`` with a tracer."""
+    t0 = time.perf_counter()
+    if tracer is None:
+        with scope:
+            out = fn(*args)
+        res = fetch(out)
+        return res, None, {"wall_s": time.perf_counter() - t0}
+    with tracer.activate():
+        with span("compile", **span_args):
+            with span("compile.lower"), scope:
+                lowered = fn.lower(*args)
+            compiled = lowered.compile()
+        t1 = time.perf_counter()
+        with span("execute", **span_args):
+            res = fetch(compiled(*args))
+    t2 = time.perf_counter()
+    return res, compiled, {"wall_s": t2 - t0, "compile_s": t1 - t0,
+                           "execute_s": t2 - t1}
+
+
 def run_compiled(cfg, spec: TrainerSpec, tasks: list[TaskData],
                  replay: Optional[ReplaySpec] = None,
                  device: Union[str, DeviceBackend, None] = None,
@@ -491,10 +524,8 @@ def run_compiled(cfg, spec: TrainerSpec, tasks: list[TaskData],
                                 seed_list, obs=obs)
 
     _, _, opt = _make_raw_steps(cfg, trainer, backend)
-    sched_scope = tracer.span("schedule", n_tasks=len(tasks)) \
-        if tracer is not None else contextlib.nullcontext()
     inputs, scheds = [], []
-    with sched_scope:
+    with activate(tracer), span("schedule", n_tasks=len(tasks)):
         for s in (seed_list if seed_list is not None else [trainer.seed]):
             tsp = dataclasses.replace(trainer, seed=s)
             inp, sched = _build_seed_inputs(cfg, tsp, rspec, backend,
@@ -573,28 +604,10 @@ def run_compiled(cfg, spec: TrainerSpec, tasks: list[TaskData],
         fn = jax.jit(run, donate_argnums=donate)
         scope = contextlib.nullcontext()
 
-    t0 = time.perf_counter()
-    compile_s = execute_s = None
-    if tracer is not None:
-        # Lower ahead of time so the compile span excludes execution.
-        # The telemetry scale scope wraps the *lowering* — trace-time
-        # pending deltas are what the multiplier applies to.
-        with tracer.span("compile", backend=backend.name,
-                         n_tasks=n_tasks, steps_per_task=S):
-            with scope:
-                lowered = fn.lower(*stacked, eval_x, eval_y, *eval_extra)
-            compiled_fn = lowered.compile()
-        compile_s = time.perf_counter() - t0
-        t1 = time.perf_counter()
-        with tracer.span("execute", backend=backend.name):
-            res = compiled_fn(*stacked, eval_x, eval_y, *eval_extra)
-            res = jax.tree.map(np.asarray, res)
-        execute_s = time.perf_counter() - t1
-    else:
-        with scope:
-            res = fn(*stacked, eval_x, eval_y, *eval_extra)
-        res = jax.tree.map(np.asarray, res)
-    wall_s = time.perf_counter() - t0
+    res, compiled_fn, times = _compile_and_run(
+        fn, (*stacked, eval_x, eval_y, *eval_extra), tracer=tracer,
+        scope=scope, fetch=lambda r: jax.tree.map(np.asarray, r),
+        backend=backend.name, n_tasks=n_tasks, steps_per_task=S)
     obs_streams = res.pop("obs", None)
 
     # Host-side accounting of the data-dependent write pulses the scan
@@ -635,11 +648,9 @@ def run_compiled(cfg, spec: TrainerSpec, tasks: list[TaskData],
         if res["dev_state"]:
             out["device_state"] = res["dev_state"]
     out["compiled"] = True
-    out["wall_s"] = wall_s
     out["steps_per_task"] = S
-    if compile_s is not None:
-        out["compile_s"] = compile_s
-        out["execute_s"] = execute_s
+    out.update(times)
+    if compiled_fn is not None:
         out["executable"] = compiled_fn
     if obs_on:
         from repro.obs.runlog import build_runlog, drift_stream
@@ -744,9 +755,7 @@ def run_sweep(scenarios: Sequence[str], backends: Sequence[str],
                     backend.tracker = EnduranceTracker()
             tracer = getattr(obs, "tracer", None) if obs is not None \
                 else None
-            cell_scope = tracer.span(f"cell:{sc_name}/{be_name}") \
-                if tracer is not None else contextlib.nullcontext()
-            with cell_scope:
+            with activate(tracer), span(f"cell:{sc_name}/{be_name}"):
                 res = run_compiled(cfg, tsp, tasks, replay=rsp,
                                    device=backend, seeds=seeds,
                                    uniform=sc.uniform, obs=obs,

@@ -17,7 +17,6 @@ decode activity on its telemetry when ``ServeConfig.meter`` is set.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import time
 from collections import deque
@@ -30,6 +29,7 @@ import numpy as np
 from repro.backends import DeviceBackend, inference_backend
 from repro.configs.base import ModelConfig
 from repro.models import lm
+from repro.obs.tracer import activate, span
 
 
 @dataclasses.dataclass
@@ -166,11 +166,6 @@ class ServeEngine:
         self.queue.append(req)
         return req
 
-    def _tracer_span(self, name: str, **args):
-        tracer = self.scfg.tracer
-        return tracer.span(name, **args) if tracer is not None \
-            else contextlib.nullcontext()
-
     def _finish(self, req: Request) -> None:
         req.done = True
         req.t_done = self.scfg.clock()
@@ -203,8 +198,8 @@ class ServeEngine:
                 # Prefill the prompt token-by-token through the decode
                 # path (single compiled executable; a production engine
                 # adds a chunked-prefill fast path).
-                with self._tracer_span("serve.prefill", rid=req.rid,
-                                       prompt_len=len(req.prompt)):
+                with span("serve.prefill", rid=req.rid,
+                          prompt_len=len(req.prompt)):
                     for t in req.prompt[:-1]:
                         self._advance_slot(slot, t, sample=False)
                 req.tokens = []
@@ -234,7 +229,8 @@ class ServeEngine:
     # ------------------------------------------------------------------
     def step(self) -> int:
         """Advance every active slot one token. Returns #active slots."""
-        with self._tracer_span("serve.step", step=self.steps_run):
+        with activate(self.scfg.tracer), \
+                span("serve.step", step=self.steps_run):
             return self._step_inner()
 
     def _step_inner(self) -> int:

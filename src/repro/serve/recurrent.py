@@ -32,7 +32,6 @@ hand-computed values under a scripted clock.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 import time
@@ -46,6 +45,7 @@ import numpy as np
 from repro.backends import DeviceBackend, get_backend
 from repro.core.continual import _meter_chip_step
 from repro.core.miru import MiRUConfig, miru_apply_readout
+from repro.obs.tracer import activate, annotate, span
 from repro.serve.slab import StateSlab
 from repro.telemetry.meters import SEQUENCES
 
@@ -119,7 +119,12 @@ class RecurrentServeConfig:
     #: Injectable wall clock (seconds). Latency/queue-wait/decode
     #: histograms read only this — tests drive it with a script.
     clock: Callable[[], float] = time.perf_counter
-    #: Optional repro.obs.Tracer: a span per engine step.
+    #: Optional repro.obs.Tracer, active during each engine step: a
+    #: ``serve.step`` span. Its parts, ``serve.admit`` (slab spills and
+    #: reloads inside), ``serve.gather``, ``serve.dispatch`` and
+    #: ``serve.retire``, are profiler annotations only: as tracer events,
+    #: several a step at hundreds of steps a second, they would multiply
+    #: the spans a trace reduction scans for every device idle gap.
     tracer: Optional[Any] = None
 
 
@@ -233,11 +238,6 @@ class RecurrentServeEngine:
 
         return jax.jit(step_fn, donate_argnums=(1,))
 
-    def _span(self, name: str, **args):
-        tracer = self.scfg.tracer
-        return tracer.span(name, **args) if tracer is not None \
-            else contextlib.nullcontext()
-
     # ------------------------------------------------------------------
     # Submission / admission
     # ------------------------------------------------------------------
@@ -317,25 +317,28 @@ class RecurrentServeEngine:
         """Admit, advance every scheduled stream by up to ``chunk``
         frames, retire materialized output. Returns the number of
         streams scheduled into this step's batch."""
-        with self._span("serve.step", step=self.steps_run):
+        with activate(self.scfg.tracer), \
+                span("serve.step", step=self.steps_run):
             return self._step_inner()
 
     def _step_inner(self) -> int:
-        self._admit()
+        with annotate("serve.admit"):
+            self._admit()
         S, C = self.scfg.batch_slots, self.scfg.chunk
         entries = []
-        x = np.zeros((S, C, self.cfg.n_x), np.float32)
-        n_steps = np.zeros((S,), np.int32)
-        for uid, req in self._active.items():
-            if req.cursor >= req.n_frames:
-                continue                     # retiring via the pipeline
-            slot = self.slab.slot(uid)
-            c = min(C, req.n_frames - req.cursor)
-            x[slot, :c] = req.frames[req.cursor:req.cursor + c]
-            n_steps[slot] = c
-            entries.append((req, slot, req.cursor, c))
-            req.cursor += c
-            self.slab.touch(uid)
+        with annotate("serve.gather"):
+            x = np.zeros((S, C, self.cfg.n_x), np.float32)
+            n_steps = np.zeros((S,), np.int32)
+            for uid, req in self._active.items():
+                if req.cursor >= req.n_frames:
+                    continue                 # retiring via the pipeline
+                slot = self.slab.slot(uid)
+                c = min(C, req.n_frames - req.cursor)
+                x[slot, :c] = req.frames[req.cursor:req.cursor + c]
+                n_steps[slot] = c
+                entries.append((req, slot, req.cursor, c))
+                req.cursor += c
+                self.slab.touch(uid)
         if entries:
             # Fault-injection point: the chip dies mid-step, before this
             # dispatch consumed any RNG — so the retry on the replacement
@@ -346,10 +349,11 @@ class RecurrentServeEngine:
             if attempt in self.scfg.fail_at_steps:
                 self._chip_failure(entries)
                 return len(entries)
-            self._rng, sub = jax.random.split(self._rng)
-            self.slab.h, logits = self._step(
-                self.params, self.slab.h, jnp.asarray(x),
-                jnp.asarray(n_steps), sub)
+            with annotate("serve.dispatch"):
+                self._rng, sub = jax.random.split(self._rng)
+                self.slab.h, logits = self._step(
+                    self.params, self.slab.h, jnp.asarray(x),
+                    jnp.asarray(n_steps), sub)
             self._inflight.append((logits, entries))
             self.steps_run += 1
         # Retire: with pipelining keep one dispatch in flight so the
@@ -388,12 +392,13 @@ class RecurrentServeEngine:
             self.slab.pin(uid)
 
     def _retire(self, logits: jax.Array, entries: list) -> None:
-        arr = np.asarray(logits)             # blocks until step done
-        for req, slot, start, c in entries:
-            req._logits[start:start + c] = arr[slot, :c]
-            req.emitted += c
-            if req.emitted >= req.n_frames:
-                self._finish(req)
+        with annotate("serve.retire"):
+            arr = np.asarray(logits)         # blocks until step done
+            for req, slot, start, c in entries:
+                req._logits[start:start + c] = arr[slot, :c]
+                req.emitted += c
+                if req.emitted >= req.n_frames:
+                    self._finish(req)
 
     def _finish(self, req: StreamRequest) -> None:
         req.done = True

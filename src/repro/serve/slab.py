@@ -38,6 +38,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.tracer import annotate
+
 __all__ = ["StateSlab", "SlabFullError"]
 
 
@@ -128,8 +130,9 @@ class StateSlab:
         self._lru[uid] = None
         if uid in self._spill:
             # Returning user: reload the spilled row bit-identically.
-            self.h = _row_set(self.h, slot,
-                              jnp.asarray(self._spill.pop(uid), self.dtype))
+            with annotate("serve.slab.reload"):
+                self.h = _row_set(self.h, slot, jnp.asarray(
+                    self._spill.pop(uid), self.dtype))
             self.reloads += 1
         else:
             # New user: fresh zero state (the slot may hold a departed
@@ -166,7 +169,9 @@ class StateSlab:
         if uid in self._pinned:
             raise ValueError(f"cannot evict pinned uid {uid!r}")
         slot = self._slot_of.pop(uid)
-        self._spill[uid] = np.asarray(_row_get(self.h, slot))
+        with annotate("serve.slab.evict"):
+            # Waits for the step in flight, which writes self.h.
+            self._spill[uid] = np.asarray(_row_get(self.h, slot))
         self._uid_of[slot] = None
         self._free.append(slot)
         del self._lru[uid]

@@ -34,6 +34,8 @@ from typing import Mapping, Optional
 import jax
 import numpy as np
 
+from repro.obs.tracer import span
+
 # Canonical meter names (energy.py keys off these).
 MACS = "macs"                        # multiply-accumulates per tile
 VMM_ROWS = "vmm_rows"                # row-vector crossbar accesses
@@ -167,7 +169,8 @@ class Telemetry:
         self._pending.clear()
 
         def _cb():
-            self._add(snap)
+            with span("telemetry.callback"):
+                self._add(snap)
 
         from jax.experimental import io_callback
         io_callback(_cb, None)

@@ -23,6 +23,7 @@ import json
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.backends import get_backend
 from repro.core.continual import ReplaySpec, TrainerSpec, run_continual
 from repro.obs import (Histogram, JsonlSink, ObsSpec, RunLog,
@@ -270,11 +271,10 @@ def test_tracer_nesting_summary_and_export(tmp_path):
     with tr.span("outer", tag=1):
         with tr.span("inner"):
             pass
-        tr.instant("mark")
     tr.counter("queue", depth=3)
     evs = tr.events()
     names = [e["name"] for e in evs]
-    assert "outer" in names and "inner" in names and "mark" in names
+    assert "outer" in names and "inner" in names
     summ = tr.summary()
     # inner's time is contained in outer's: top-level totals don't
     # double-count.
@@ -308,6 +308,180 @@ def test_run_compiled_tracer_spans(small_setup):
     # AOT separation: the compile span dominates this tiny run.
     summ = tr.summary()
     assert summ["compile"]["total_s"] > summ["execute"]["total_s"]
+
+
+def _spans(tr, name):
+    return [e for e in tr.events() if e["ph"] == "X" and e["name"] == name]
+
+
+def _inside(e, outer, slack_us=1.0):
+    return (outer["ts"] - slack_us <= e["ts"]
+            and e["ts"] + e["dur"] <= outer["ts"] + outer["dur"] + slack_us)
+
+
+def test_span_without_an_active_tracer_records_nothing():
+    tr = Tracer()
+    assert obs.span("a") is obs.span("b")          # one shared null
+    assert obs.annotate("a") is obs.span("b")
+    with obs.span("orphan"):
+        pass
+    with tr.activate():
+        with obs.span("kept", k=1):
+            with Tracer().activate():
+                with obs.span("elsewhere"):
+                    pass
+            with obs.span("kept.child"):
+                pass
+    with obs.span("after"):
+        pass
+    assert [e["name"] for e in tr.events() if e["ph"] == "X"] == \
+        ["kept.child", "kept"]
+    assert _spans(tr, "kept")[0]["args"] == {"k": 1}
+
+
+def test_tracer_record_places_a_finished_span_on_its_clock():
+    import time
+    tr = Tracer()
+    t0 = time.perf_counter()
+    with tr.span("outer"):
+        time.sleep(0.002)
+    tr.record("after", t0 + 0.001, 0.0005, n=2)
+    outer, = _spans(tr, "outer")
+    rec, = _spans(tr, "after")
+    assert rec["ts"] == pytest.approx(outer["ts"] + 1e3, abs=50)
+    assert rec["dur"] == pytest.approx(500, abs=1)
+    assert rec["args"] == {"n": 2}
+
+
+def test_run_compiled_records_compile_schedule_and_callback_spans(
+        small_setup):
+    cfg, trainer, tasks = small_setup
+    backend = get_backend("wbs")
+    backend.telemetry.enable()
+    tr = Tracer()
+    run_compiled(cfg, trainer, tasks, replay=ReplaySpec(capacity=32),
+                 device=backend, obs=ObsSpec(metrics=False, tracer=tr))
+    compile_, = _spans(tr, "compile")
+    schedule, = _spans(tr, "schedule")
+    execute, = _spans(tr, "execute")
+    lower, = _spans(tr, "compile.lower")
+    assert _inside(lower, compile_)
+    backend_compiles = [e for e in _spans(tr, "compile.xla")
+                        + _spans(tr, "compile.cache_load")
+                        if _inside(e, compile_)]
+    assert len(backend_compiles) == 1               # the one program
+    quant = _spans(tr, "replay.quantize")
+    assert quant and all(_inside(e, schedule) for e in quant)
+    cb, = _spans(tr, "telemetry.callback")
+    assert _inside(cb, execute)
+
+
+def test_second_unmetered_call_loads_from_the_persistent_cache(
+        small_setup, tmp_path):
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    cfg, trainer, tasks = small_setup
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs")
+    saved = {n: getattr(jax.config, n) for n in names}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    cc.reset_cache()
+    try:
+        found = []
+        for _ in range(2):
+            tr = Tracer()
+            run_compiled(cfg, trainer, tasks,
+                         replay=ReplaySpec(capacity=32), device="ideal",
+                         obs=ObsSpec(metrics=False, tracer=tr))
+            compile_, = _spans(tr, "compile")
+            found.append(sorted(
+                e["name"] for e in _spans(tr, "compile.xla")
+                + _spans(tr, "compile.cache_load")
+                if _inside(e, compile_)))
+    finally:
+        for n, v in saved.items():
+            jax.config.update(n, v)
+        cc.reset_cache()
+    assert found == [["compile.xla"], ["compile.cache_load"]]
+
+
+def _serve_bursts(eng, rng, uids, n_x):
+    reqs = [eng.submit(rng.uniform(-1, 1, (int(rng.integers(3, 9)), n_x))
+                       .astype(np.float32), uid=u) for u in uids]
+    eng.run_until_drained()
+    return reqs
+
+
+def _serve_with(tracer):
+    import jax
+    from repro.core.miru import MiRUConfig, init_miru_params
+    from repro.serve import RecurrentServeConfig, RecurrentServeEngine
+    cfg = MiRUConfig(n_x=6, n_h=12, n_y=4)
+    params = init_miru_params(jax.random.PRNGKey(0), cfg)
+    eng = RecurrentServeEngine(cfg, RecurrentServeConfig(
+        batch_slots=2, chunk=4, device="wbs", fresh_meter=True,
+        tracer=tracer), params)
+    rng = np.random.default_rng(0)
+    reqs = []
+    for _ in range(2):             # five users on two slots, twice
+        reqs += _serve_bursts(eng, rng, range(5), cfg.n_x)
+    return eng, [r.logits for r in reqs]
+
+
+def test_serve_step_parts_are_annotations_inside_each_step(tmp_path):
+    """The step's parts and the slab's spills and reloads sit on the
+    profiler's host plane inside their ``serve.step``; of the serve
+    spans, the tracer itself records the steps only."""
+    import glob
+
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        eng, _ = _serve_with(tr)
+    finally:
+        jax.profiler.stop_trace()
+    assert {e["name"] for e in tr.events() if e["ph"] == "X"
+            and e["name"].startswith("serve.")} == {"serve.step"}
+    path, = glob.glob(f"{tmp_path}/plugins/profile/*/*.xplane.pb")
+    host = [e for p in jax.profiler.ProfileData.from_file(path).planes
+            if p.name.startswith("/host:") for line in p.lines
+            for e in line.events if e.name.startswith("serve.")]
+
+    def named(n):
+        return [(e.start_ns, e.start_ns + e.duration_ns) for e in host
+                if e.name == n]
+
+    steps = named("serve.step")
+    assert len(steps) == len(_spans(tr, "serve.step")) >= eng.steps_run
+
+    def owners(iv, outer):
+        return sum(s <= iv[0] and iv[1] <= e for s, e in outer)
+
+    for part in ("serve.admit", "serve.gather"):
+        assert len(named(part)) == len(steps)
+    assert len(named("serve.dispatch")) == eng.steps_run
+    for part in ("serve.admit", "serve.gather", "serve.dispatch",
+                 "serve.retire"):
+        assert all(owners(iv, steps) == 1 for iv in named(part)), part
+    stats = eng.slab.stats()
+    for part, count in (("serve.slab.evict", stats["evictions"]),
+                        ("serve.slab.reload", stats["reloads"])):
+        assert len(named(part)) == count > 0
+        assert all(owners(iv, named("serve.admit")) == 1
+                   for iv in named(part))
+
+
+def test_served_logits_are_bitwise_equal_with_and_without_a_tracer():
+    _, plain = _serve_with(None)
+    _, traced = _serve_with(Tracer())
+    assert len(plain) == len(traced) == 10
+    for a, b in zip(plain, traced):
+        np.testing.assert_array_equal(a, b)
 
 
 # ---------------------------------------------------------------------------
@@ -356,16 +530,6 @@ def test_run_record_schema_and_jsonl_roundtrip(tmp_path):
     assert len(rows) == 2
     assert rows[0]["metrics"]["MA"] == 0.9
     assert rows[1]["metrics"]["MA"] == 0.8
-
-
-def test_bench_history_append(tmp_path, monkeypatch):
-    import benchmarks.common as bc
-    monkeypatch.setattr(bc, "HISTORY", tmp_path / "history")
-    p = bc.append_history("unit_bench", {"us": 1.5},
-                          gates={"g": True})
-    rows = [json.loads(l) for l in p.read_text().splitlines()]
-    assert rows[0]["kind"] == "bench"
-    assert rows[0]["gates"] == {"g": True}
 
 
 # ---------------------------------------------------------------------------
