@@ -20,7 +20,13 @@ reports) is derived from it.
 Telemetry is threaded through jit-exactly: the metered forward's
 interior flush is suppressed (``Telemetry.deferred``), per-trace deltas
 are multiplied by the scan/map/vmap multiplicities (``Telemetry.scaled``)
-and drained through one io_callback per compiled execution.
+and taken out at the program's one flush point at trace time
+(``Telemetry.take_pending``); the host credits them to the calling
+backend after each execution. The program thus reads nothing of the
+call it was traced for beyond its inputs' structure, so
+:func:`run_compiled` keeps it: a later call of the same structure (say,
+the next trainer seed) runs the cached executable without tracing,
+lowering or compiling, and credits the deltas stored with it.
 Data-dependent write pulses are summed inside the scan as per-device
 count maps and folded into the telemetry/endurance tracker host-side.
 
@@ -43,14 +49,16 @@ same way it applies ``trainer_overrides``.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import time
-from typing import Any, Optional, Sequence, Union
+from typing import Any, Callable, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax._src import config as jax_config
 
 from repro.backends import DeviceBackend, get_backend
 from repro.core.continual import (ReplaySpec, TrainerSpec,
@@ -66,7 +74,8 @@ from repro.scenarios.metrics import continual_metrics
 from repro.scenarios.registry import get_scenario
 from repro.utils import zeros_like_varying
 
-__all__ = ["run_compiled", "run_sweep", "scenario_miru_config"]
+__all__ = ["clear_program_cache", "run_compiled", "run_sweep",
+           "scenario_miru_config"]
 
 
 # ---------------------------------------------------------------------------
@@ -180,7 +189,8 @@ def _build_seed_inputs(cfg, trainer: TrainerSpec, rspec: ReplaySpec,
 def _make_run_fn(cfg, trainer: TrainerSpec, backend: DeviceBackend,
                  n_tasks: int, S: int, track_writes: bool, baseline: bool,
                  ingraph_rspec: Optional[ReplaySpec] = None,
-                 obs_metrics: bool = False):
+                 obs_metrics: bool = False,
+                 flush: Optional[Callable[[], None]] = None):
     """Build the jitted whole-protocol run. When ``ingraph_rspec`` names
     an in-graph replay policy (loss_aware), the step is the replay-
     wrapped one and the device-resident buffer rides the scan carry —
@@ -190,7 +200,11 @@ def _make_run_fn(cfg, trainer: TrainerSpec, backend: DeviceBackend,
     (write pulses, Σ|ΔG|, replay occupancy) through the scan as extra
     ``ys`` outputs — pure reads of values the step already computes, so
     the training results are unchanged; False (the default) emits
-    exactly the pre-obs trace."""
+    exactly the pre-obs trace.
+
+    ``flush`` is called once, at the top level of the trace, where the
+    pending telemetry deltas are complete; by default
+    ``Telemetry.emit_pending`` embeds them in an io_callback."""
     raw_train, raw_eval, _ = _make_raw_steps(cfg, trainer, backend)
     ingraph_step = None
     if ingraph_rspec is not None:
@@ -199,6 +213,7 @@ def _make_run_fn(cfg, trainer: TrainerSpec, backend: DeviceBackend,
     if obs_metrics:
         from repro.obs.runlog import step_stats
     tele = backend.telemetry
+    flush = flush if flush is not None else tele.emit_pending
 
     def run(params, opt_state, dev_state, rstate, xs, ys, step_keys,
             eval_keys, eval_x, eval_y):
@@ -246,7 +261,7 @@ def _make_run_fn(cfg, trainer: TrainerSpec, backend: DeviceBackend,
                     task_body,
                     (params, opt_state, dev_state, wc0, rstate),
                     (xs, ys, step_keys, eval_keys, replay_on))
-        tele.emit_pending()
+        flush()
         params, opt_state, dev_state, wcounts, rstate = carry
         if obs_metrics:
             losses, pulses, dgs, occs = step_ys
@@ -265,7 +280,8 @@ def _make_run_fn(cfg, trainer: TrainerSpec, backend: DeviceBackend,
 
 def _make_masked_run_fn(cfg, trainer: TrainerSpec, backend: DeviceBackend,
                         n_tasks: int, total_real_steps: int,
-                        track_writes: bool, baseline: bool):
+                        track_writes: bool, baseline: bool,
+                        flush: Callable[[], None]):
     """The masked twin of :func:`_make_run_fn` for padded ragged
     schedules: row-validity/true-length aware steps
     (:func:`repro.core.continual._make_masked_steps`), step-axis padding
@@ -280,7 +296,8 @@ def _make_masked_run_fn(cfg, trainer: TrainerSpec, backend: DeviceBackend,
     tolerance contract of :mod:`repro.data.ragged`, gated in
     benchmarks/data_bench.py). In-graph replay is unsupported here
     (:func:`run_compiled` raises before getting this far), so
-    ``rstate`` never rides the carry."""
+    ``rstate`` never rides the carry. ``flush`` is the program's one
+    telemetry flush point, as in :func:`_make_run_fn`."""
     from repro.core.continual import _make_masked_steps
     raw_train, raw_eval, _ = _make_masked_steps(cfg, trainer, backend)
     tele = backend.telemetry
@@ -342,7 +359,7 @@ def _make_masked_run_fn(cfg, trainer: TrainerSpec, backend: DeviceBackend,
                 task_body, (params, opt_state, dev_state, wc0),
                 (xs, ys, step_keys, eval_keys, step_valid,
                  row_valid, lengths))
-        tele.emit_pending()
+        flush()
         params, opt_state, dev_state, wcounts = carry
         return {"params": params, "dev_state": dev_state,
                 "R_full": R_full, "losses": losses,
@@ -413,34 +430,88 @@ def _fallback_python(cfg, trainer, tasks, rspec, backend, seeds,
     return out
 
 
+# Compiled sweep programs by what their trace reads (``_program_key``),
+# least recently used first; each with the telemetry deltas its trace
+# left for the host to credit per execution.
+_PROGRAMS: collections.OrderedDict = collections.OrderedDict()
+_PROGRAMS_MAX = 8
+
+
+def clear_program_cache() -> None:
+    """Forget the cached sweep programs: the next call of every structure
+    traces, lowers and compiles again."""
+    _PROGRAMS.clear()
+
+
+def _program_key(backend: DeviceBackend, args: tuple, *parts):
+    """The cache key of a sweep program: ``parts`` (the configs and the
+    program's structure), the backend's class and settings, the
+    arguments' tree, avals and placement, the devices, and the JAX
+    configuration ``jax.jit`` keys on (default matmul precision and
+    device among it). None, so the call compiles, when any of it cannot
+    be hashed by value."""
+    leaves, tree = jax.tree.flatten(args)
+    settings = sorted((k, v) for k, v in vars(backend).items()
+                      if k not in ("telemetry", "tracker"))
+    key = (parts, type(backend), tuple(settings), tree,
+           tuple((jax.typeof(x), getattr(x, "sharding", None))
+                 for x in leaves),
+           tuple(jax.devices()), jax.default_backend(),
+           jax_config.trace_context())
+    try:
+        hash(key)
+    except TypeError:
+        return None
+    return key
+
+
 def _compile_and_run(fn, args: tuple, *, tracer, scope, fetch,
+                     key=None, meta: Optional[dict] = None,
                      **span_args) -> tuple[Any, Any, dict]:
     """Run the jitted ``fn`` on ``args`` and ``fetch`` its outputs to the
     host. ``scope`` wraps whatever traces ``fn`` (telemetry records its
-    deltas while tracing). Without a tracer: the ordinary dispatch. With
-    one: lowered ahead of time under the ``compile`` span (a
-    ``compile.lower`` child; the backend compile or cache load records
-    itself), then executed and fetched under ``execute``, so compile
-    time excludes execution.
+    deltas while tracing). Without a tracer or a ``key``: the ordinary
+    dispatch. Otherwise lowered ahead of time under the ``compile`` span
+    (a ``compile.lower`` child; the backend compile or cache load
+    records itself), then executed and fetched under ``execute``, so
+    compile time excludes execution.
+
+    With a ``key`` (:func:`_program_key`) the executable is cached, with
+    a copy of the dict ``meta`` as the trace left it; a later call under the same
+    key runs the cached executable (``compile`` then holds the lookup, in
+    a ``compile.reuse`` child) and gets the cached copy back in ``meta``.
 
     Returns the host outputs, the compiled executable (None without a
     tracer) and the timings: ``wall_s``, plus ``compile_s`` and
     ``execute_s`` with a tracer."""
     t0 = time.perf_counter()
-    if tracer is None:
+    if tracer is None and key is None:
         with scope:
             out = fn(*args)
         res = fetch(out)
         return res, None, {"wall_s": time.perf_counter() - t0}
-    with tracer.activate():
+    with activate(tracer):
         with span("compile", **span_args):
-            with span("compile.lower"), scope:
-                lowered = fn.lower(*args)
-            compiled = lowered.compile()
+            entry = _PROGRAMS.get(key) if key is not None else None
+            if entry is not None:
+                with span("compile.reuse"):
+                    _PROGRAMS.move_to_end(key)
+                    compiled, cached = entry
+                    meta.update(cached)
+            else:
+                with span("compile.lower"), scope:
+                    lowered = fn.lower(*args)
+                compiled = lowered.compile()
+                if key is not None:
+                    _PROGRAMS[key] = (compiled, dict(meta))
+                    if len(_PROGRAMS) > _PROGRAMS_MAX:
+                        _PROGRAMS.popitem(last=False)
         t1 = time.perf_counter()
         with span("execute", **span_args):
             res = fetch(compiled(*args))
     t2 = time.perf_counter()
+    if tracer is None:
+        return res, None, {"wall_s": t2 - t0}
     return res, compiled, {"wall_s": t2 - t0, "compile_s": t1 - t0,
                            "execute_s": t2 - t1}
 
@@ -482,6 +553,14 @@ def run_compiled(cfg, spec: TrainerSpec, tasks: list[TaskData],
     program itself comes back as ``"executable"`` (a
     ``jax.stages.Compiled``, for inspecting what it runs). ``obs=None``
     (the default) compiles and runs the exact pre-obs program.
+
+    The compiled program is cached by everything its trace reads (the
+    configs, the backend's class and settings, the arguments' shapes,
+    the devices and JAX's configuration), not by the seeds, which reach
+    it as argument values: a later call of the same structure runs the
+    cached executable. Its telemetry deltas, fixed at trace time, are
+    credited to the calling backend on the host after each execution.
+    :func:`clear_program_cache` empties the cache.
 
     ``pad`` is a :class:`repro.data.ragged.PadPolicy`: ragged streams
     (unequal n_train/n_test/sequence length across tasks) pad onto one
@@ -566,14 +645,22 @@ def run_compiled(cfg, spec: TrainerSpec, tasks: list[TaskData],
                 (T, F)) if in_graph else sched.replay_traffic
             if traffic:
                 tele.record(traffic)
+    # The program's telemetry deltas, taken out at its flush point while
+    # it traces and credited below after it runs.
+    deltas: dict[str, int] = {}
+
+    def flush():
+        deltas.update(tele.take_pending())
+
     if masked:
         run = _make_masked_run_fn(cfg, trainer, backend, n_tasks,
-                                  total_real, track_writes, baseline)
+                                  total_real, track_writes, baseline,
+                                  flush)
     else:
         run = _make_run_fn(cfg, trainer, backend, n_tasks, S,
                            track_writes, baseline,
                            ingraph_rspec=rspec if in_graph else None,
-                           obs_metrics=obs_on)
+                           obs_metrics=obs_on, flush=flush)
 
     eval_x = jnp.asarray(np.stack([t.x_test for t in tasks]))
     eval_y = jnp.asarray(np.stack([t.y_test for t in tasks]))
@@ -604,10 +691,23 @@ def run_compiled(cfg, spec: TrainerSpec, tasks: list[TaskData],
         fn = jax.jit(run, donate_argnums=donate)
         scope = contextlib.nullcontext()
 
+    args = (*stacked, eval_x, eval_y, *eval_extra)
+    # Everything the trace reads. No traced function reads the trainer's
+    # seed: seeds enter as the arguments' values.
+    key = _program_key(
+        backend, args, cfg, dataclasses.replace(trainer, seed=0),
+        dataclasses.replace(rspec, policy=rspec.resolved_policy),
+        tele.enabled, track_writes, n_tasks, S, total_real, baseline,
+        obs_on, masked, len(seed_list) if many else None, donate)
+    # Deltas an earlier trace left pending count once, as the program's
+    # io_callback used to count them; they are no part of this program.
+    tele.credit(tele.take_pending())
     res, compiled_fn, times = _compile_and_run(
-        fn, (*stacked, eval_x, eval_y, *eval_extra), tracer=tracer,
-        scope=scope, fetch=lambda r: jax.tree.map(np.asarray, r),
+        fn, args, tracer=tracer, scope=scope,
+        fetch=lambda r: jax.tree.map(np.asarray, r), key=key, meta=deltas,
         backend=backend.name, n_tasks=n_tasks, steps_per_task=S)
+    # One execution per call: the trace's deltas count once.
+    tele.credit(deltas)
     obs_streams = res.pop("obs", None)
 
     # Host-side accounting of the data-dependent write pulses the scan

@@ -20,6 +20,10 @@ The accounting protocol that is exact under jit/scan/grad:
     single ``io_callback`` that fires once per *execution* of the compiled
     program. ``core/continual.py`` places these flush points in the
     forward and in every train/eval step.
+  * :meth:`take_pending` — the alternative at such a point for a caller
+    that runs the program once per call and credits the deltas on the
+    host itself (:meth:`credit`): the compiled scenario sweep, whose
+    cached program must not carry one call's accumulator into the next.
 
 Data-dependent counts (write pulses — only nonzero updates cost pulses)
 cannot be static; they are metered host-side from the concrete ``applied``
@@ -140,7 +144,8 @@ class Telemetry:
         finally:
             self._deferred = prev
 
-    def _add(self, deltas: Mapping[str, int]) -> None:
+    def credit(self, deltas: Mapping[str, int]) -> None:
+        """Count ``deltas`` now, unscaled."""
         for k, v in deltas.items():
             self.counters[k] += v
 
@@ -156,7 +161,7 @@ class Telemetry:
             for k, v in scaled.items():
                 self._pending[k] = self._pending.get(k, 0) + v
         else:
-            self._add(scaled)
+            self.credit(scaled)
 
     def emit_pending(self) -> None:
         """Drain the pending buffer into one ``io_callback`` that fires per
@@ -170,10 +175,17 @@ class Telemetry:
 
         def _cb():
             with span("telemetry.callback"):
-                self._add(snap)
+                self.credit(snap)
 
         from jax.experimental import io_callback
         io_callback(_cb, None)
+
+    def take_pending(self) -> dict[str, int]:
+        """Return and clear the pending buffer: the deltas
+        :meth:`emit_pending` would embed, for a caller that credits them
+        itself after each execution of the program it traced."""
+        snap, self._pending = self._pending, {}
+        return snap
 
     # ------------------------------------------------------------------
     # Meter hooks (static, shape-derived)
@@ -210,7 +222,7 @@ class Telemetry:
         deltas = {f"{WRITE_PULSES}/{k}": int(np.asarray(m).sum())
                   for k, m in masks.items()}
         deltas[WRITE_EVENTS] = 1
-        self._add(deltas)
+        self.credit(deltas)
 
     def meter_write_counts(self, counts: Mapping[str, np.ndarray],
                            events: int) -> None:
@@ -222,7 +234,7 @@ class Telemetry:
         deltas = {f"{WRITE_PULSES}/{k}": int(np.asarray(c).sum())
                   for k, c in counts.items()}
         deltas[WRITE_EVENTS] = int(events)
-        self._add(deltas)
+        self.credit(deltas)
 
     # ------------------------------------------------------------------
     def __repr__(self) -> str:

@@ -296,7 +296,15 @@ def test_tracer_span_exception_still_closes():
     assert any(e["name"] == "boom" for e in tr.events())
 
 
-def test_run_compiled_tracer_spans(small_setup):
+@pytest.fixture
+def fresh_programs():
+    """No sweep program cached: the call under test compiles."""
+    from repro.scenarios.sweep import clear_program_cache
+    clear_program_cache()
+    return clear_program_cache
+
+
+def test_run_compiled_tracer_spans(small_setup, fresh_programs):
     cfg, trainer, tasks = small_setup
     tr = Tracer()
     res = run_compiled(cfg, trainer, tasks,
@@ -354,7 +362,9 @@ def test_tracer_record_places_a_finished_span_on_its_clock():
 
 
 def test_run_compiled_records_compile_schedule_and_callback_spans(
-        small_setup):
+        small_setup, fresh_programs):
+    """The sweep's telemetry needs no callback span: its deltas are
+    credited on the host after ``execute``."""
     cfg, trainer, tasks = small_setup
     backend = get_backend("wbs")
     backend.telemetry.enable()
@@ -372,12 +382,12 @@ def test_run_compiled_records_compile_schedule_and_callback_spans(
     assert len(backend_compiles) == 1               # the one program
     quant = _spans(tr, "replay.quantize")
     assert quant and all(_inside(e, schedule) for e in quant)
-    cb, = _spans(tr, "telemetry.callback")
-    assert _inside(cb, execute)
+    assert not _spans(tr, "telemetry.callback")
+    assert backend.telemetry.total("macs") > 0
 
 
 def test_second_unmetered_call_loads_from_the_persistent_cache(
-        small_setup, tmp_path):
+        small_setup, tmp_path, fresh_programs):
     import jax
     from jax.experimental.compilation_cache import compilation_cache as cc
     cfg, trainer, tasks = small_setup
@@ -390,6 +400,7 @@ def test_second_unmetered_call_loads_from_the_persistent_cache(
     try:
         found = []
         for _ in range(2):
+            fresh_programs()
             tr = Tracer()
             run_compiled(cfg, trainer, tasks,
                          replay=ReplaySpec(capacity=32), device="ideal",
