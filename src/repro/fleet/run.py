@@ -15,12 +15,22 @@ gets:
   * its own per-cell G⁺/G⁻ initial programming (re-programmed under the
     chip's own ``prog_sigma`` with a chip-local key).
 
+The program is cached as ``run_compiled``'s is
+(:func:`repro.scenarios.sweep._compile_and_run` keyed by
+:func:`~repro.scenarios.sweep._program_key`): the key covers the
+configs, the backend, the fleet's profile and size, the shard count and
+the mesh, not the fleet seed. The per-chip seeds, heterogeneity draws and
+programmed pairs are argument values, so a later fleet of the same
+structure runs the cached executable without tracing or compiling.
+
 Telemetry stays jit-exact: the shard body is traced once under
-``telemetry.scaled(n_local)`` (the per-shard device count), and the one
-deferred ``io_callback`` fires once *per shard* at run time — k shards
-× n_local-scaled deltas = the whole fleet's counters, independent of
-mesh shape. Data-dependent write pulses come back as per-device count
-maps, so lifetime projections keep their per-chip resolution.
+``telemetry.scaled(n_local)`` (the per-shard device count), its deltas
+are taken out at the flush point at trace time and kept with the cached
+program, and the host credits them once per shard after each execution
+— k shards × n_local-scaled deltas = the whole fleet's counters,
+independent of mesh shape. Data-dependent write pulses come back as
+per-device count maps, so lifetime projections keep their per-chip
+resolution.
 
 With ``het_profile="none"`` nothing is attached to the device-state
 pytree: the trace is identical to ``run_compiled``'s seed-vmapped path
@@ -30,7 +40,6 @@ tests/test_fleet.py and benchmarks/fleet_bench.py).
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any, Optional, Union
 
 import jax
@@ -52,9 +61,9 @@ from repro.obs.tracer import activate, span
 from repro.replay import get_policy_class
 from repro.scenarios.sweep import (_aggregate_seeds, _build_seed_inputs,
                                    _compile_and_run, _make_run_fn,
-                                   _summarize_run)
+                                   _program_key, _summarize_run)
 
-__all__ = ["run_fleet", "fleet_shard_count"]
+__all__ = ["run_fleet", "fleet_shard_count", "shard_fleet"]
 
 
 def fleet_shard_count(n_devices: int,
@@ -75,6 +84,19 @@ def _fetch(res: dict) -> tuple[dict, list[int]]:
     that held each shard of them."""
     devices = [s.device.id for s in res["R_full"].addressable_shards]
     return jax.tree.map(np.asarray, res), devices
+
+
+def shard_fleet(run, mesh: Mesh, axis: str):
+    """The jitted fleet program: the per-chip sweep ``run`` vmapped over
+    each shard's chips and sharded over ``mesh``'s ``axis`` (the eval
+    sets replicated). The mutated state buffers (params; the conductance
+    pairs) are donated, so the shard-local copies alias in place."""
+    ax = P(axis)
+    vrun = jax.vmap(run, in_axes=(0,) * 8 + (None, None))
+    return jax.jit(jax.shard_map(vrun, mesh=mesh,
+                                 in_specs=(ax,) * 8 + (P(), P()),
+                                 out_specs=ax),
+                   donate_argnums=(0, 2))
 
 
 def run_fleet(cfg, spec: TrainerSpec, tasks: list[TaskData],
@@ -115,8 +137,12 @@ def run_fleet(cfg, spec: TrainerSpec, tasks: list[TaskData],
     ``obs`` is a :class:`repro.obs.ObsSpec`: the result gains a
     ``"runlog"`` whose streams carry a leading ``(n_devices,)`` chip
     axis (``timeline`` reduces it — counters summed across the fleet,
-    gauges averaged), and the tracer records ``schedule`` / ``compile``
-    / ``execute`` spans plus ``compile_s``/``execute_s`` keys.
+    gauges averaged). A tracer records ``schedule`` (› ``replay.quantize``),
+    ``fleet.overlay`` (the heterogeneity draw, the per-chip
+    re-programming and the fleet fault overlay), ``compile`` (›
+    ``compile.lower`` on a miss, ``compile.reuse`` on a hit),
+    ``execute`` and ``fleet.summarize`` (the host work after it) spans,
+    plus ``compile_s``/``execute_s`` keys.
 
     Raises on ragged task streams (the fleet axis needs one trace) and
     on heterogeneity profiles with a backend that has no conductance-
@@ -170,131 +196,152 @@ def run_fleet(cfg, spec: TrainerSpec, tasks: list[TaskData],
                 (T, F)) if in_graph else sched.replay_traffic
             if traffic:
                 tele.record(traffic)
+    # The program's telemetry deltas, taken out at its flush point while
+    # it traces (once per shard body, scaled by the chips it holds) and
+    # credited on the host after each execution, once per shard.
+    deltas: dict[str, int] = {}
+
+    def flush():
+        deltas.update(tele.take_pending())
+
     run = _make_run_fn(cfg, trainer, backend, n_tasks, S, track_writes,
                        baseline, ingraph_rspec=rspec if in_graph else None,
-                       obs_metrics=obs_on)
+                       obs_metrics=obs_on, flush=flush)
 
     eval_x = jnp.asarray(np.stack([t.x_test for t in tasks]))
     eval_y = jnp.asarray(np.stack([t.y_test for t in tasks]))
     stacked = jax.tree.map(lambda *xs: jnp.stack(xs),
                            *[i.as_arrays() for i in inputs])
 
-    het = draw_heterogeneity(fleet)
-    # Host copy up front: the draws alias the donated device-state
-    # pytree ("_het" leaves), so the device buffers die with the run.
-    het_np = ({k: np.asarray(v) for k, v in het.items()}
-              if het is not None else None)
-    if het is not None:
-        # Replace the homogeneous device states with per-chip
-        # programming under each chip's own parameter draw.
-        dev_state = overlay_device_states(backend, stacked[0], seeds, het)
-        stacked = stacked[:2] + (dev_state,) + stacked[3:]
-
-    # Fleet-level fault severity: when the backend's FaultSpec carries a
-    # per-chip rate spread or a dead-chip rate, re-sample every chip's
-    # masks under its own draw (chip-local keys, traced multipliers).
-    # Without those knobs the per-seed masks from _build_seed_inputs
-    # stand, and this block leaves the program untouched.
     fspec = getattr(backend.spec, "faults", None)
-    fault_scale, dead_chips = draw_fleet_faults(fleet, fspec)
-    fault_scale_np = (np.asarray(fault_scale)
-                      if fault_scale is not None else None)
-    dead_np = np.asarray(dead_chips) if dead_chips is not None else None
-    if fault_scale is not None:
-        dev_state = stacked[2]
-        new_masks = overlay_fault_states(backend, stacked[0], seeds,
-                                         fault_scale, dead_chips, fspec)
-        dev_state = {**dev_state, "_faults": new_masks}
-        stacked = stacked[:2] + (dev_state,) + stacked[3:]
+    with activate(tracer), span("fleet.overlay", n_devices=D):
+        het = draw_heterogeneity(fleet)
+        # Host copy up front: the draws alias the donated device-state
+        # pytree ("_het" leaves), so the device buffers die with the run.
+        het_np = ({k: np.asarray(v) for k, v in het.items()}
+                  if het is not None else None)
+        if het is not None:
+            # Replace the homogeneous device states with per-chip
+            # programming under each chip's own parameter draw.
+            dev_state = overlay_device_states(backend, stacked[0], seeds,
+                                              het)
+            stacked = stacked[:2] + (dev_state,) + stacked[3:]
+
+        # Fleet-level fault severity: when the backend's FaultSpec carries
+        # a per-chip rate spread or a dead-chip rate, re-sample every
+        # chip's masks under its own draw (chip-local keys, traced
+        # multipliers). Without those knobs the per-seed masks from
+        # _build_seed_inputs stand, and this block leaves the program
+        # untouched.
+        fault_scale, dead_chips = draw_fleet_faults(fleet, fspec)
+        fault_scale_np = (np.asarray(fault_scale)
+                          if fault_scale is not None else None)
+        dead_np = np.asarray(dead_chips) if dead_chips is not None else None
+        if fault_scale is not None:
+            dev_state = stacked[2]
+            new_masks = overlay_fault_states(backend, stacked[0], seeds,
+                                             fault_scale, dead_chips, fspec)
+            dev_state = {**dev_state, "_faults": new_masks}
+            stacked = stacked[:2] + (dev_state,) + stacked[3:]
 
     n_shards = fleet_shard_count(D, max_shards)
     n_local = D // n_shards
     mesh = Mesh(np.array(jax.devices()[:n_shards]), (fleet.mesh_axis,))
-    ax = P(fleet.mesh_axis)
-    vrun = jax.vmap(run, in_axes=(0,) * 8 + (None, None))
-    # Donate the mutated state buffers (params; the conductance pairs) —
-    # the shard-local copies alias in place. The deferred telemetry
-    # callback fires once per shard over the n_local-scaled deltas, so
-    # the counter totals are mesh-shape invariant.
-    fn = jax.jit(jax.shard_map(vrun, mesh=mesh,
-                               in_specs=(ax,) * 8 + (P(), P()),
-                               out_specs=ax),
-                 donate_argnums=(0, 2))
+    fn = shard_fleet(run, mesh, fleet.mesh_axis)
+    args = (*stacked, eval_x, eval_y)
+    # Everything the trace reads. The fleet seed, the heterogeneity draws
+    # and the per-chip seeds reach the program as argument values only.
+    key = _program_key(
+        backend, args, cfg, dataclasses.replace(trainer, seed=0),
+        dataclasses.replace(rspec, policy=rspec.resolved_policy),
+        dataclasses.replace(fleet, seed=0), tele.enabled, track_writes,
+        n_tasks, S, baseline, obs_on, n_shards, mesh, "fleet")
+    # Deltas an earlier trace left pending count once; they are no part
+    # of this program.
+    tele.credit(tele.take_pending())
     (res, shard_devices), _, times = _compile_and_run(
-        fn, (*stacked, eval_x, eval_y), tracer=tracer,
-        scope=tele.scaled(n_local), fetch=_fetch, backend=backend.name,
-        n_devices=D, n_shards=n_shards)
-    obs_streams = res.pop("obs", None)
+        fn, args, tracer=tracer, scope=tele.scaled(n_local), fetch=_fetch,
+        key=key, meta=deltas, backend=backend.name, n_devices=D,
+        n_shards=n_shards)
+    # Each shard executes the body once: the deltas, already scaled by
+    # the shard's n_local chips, count once per shard, so the totals are
+    # the whole fleet's whatever the mesh shape.
+    tele.credit({k: v * n_shards for k, v in deltas.items()})
 
-    # Host-side accounting of the scan-summed write pulses — fleet
-    # totals into the meters/tracker, per-device maps kept for the
-    # population lifetime distributions.
-    wcounts = res.pop("wcounts")
-    per_device_wcounts = None
-    if track_writes and wcounts:
-        per_device_wcounts = {k: np.asarray(v) for k, v in wcounts.items()}
-        counts = {k: v.sum(axis=0) for k, v in per_device_wcounts.items()}
-        total_steps = n_tasks * S * D
-        tele.meter_write_counts(counts, total_steps)
+    with activate(tracer), span("fleet.summarize", n_devices=D):
+        obs_streams = res.pop("obs", None)
+        # Host-side accounting of the scan-summed write pulses — fleet
+        # totals into the meters/tracker, per-device maps kept for the
+        # population lifetime distributions.
+        wcounts = res.pop("wcounts")
+        per_device_wcounts = None
+        if track_writes and wcounts:
+            per_device_wcounts = {k: np.asarray(v)
+                                  for k, v in wcounts.items()}
+            counts = {k: v.sum(axis=0)
+                      for k, v in per_device_wcounts.items()}
+            total_steps = n_tasks * S * D
+            tele.meter_write_counts(counts, total_steps)
+            if backend.tracker is not None:
+                backend.tracker.record_counts(counts, total_steps)
+
+        per_device = [_summarize_run(res["R_full"][i],
+                                     res["baseline_row"][i],
+                                     res["losses"][i], baseline)
+                      for i in range(D)]
+        out: dict[str, Any] = dict(per_device[0])
+        out.update(_aggregate_seeds(per_device, seeds))
+        out["per_device"] = out.pop("per_seed")
+        out["device_seeds"] = out.pop("seeds")
+        out.update({
+            "compiled": True,
+            "fleet": fleet,
+            "n_devices": D,
+            "n_shards": n_shards,
+            "shard_devices": shard_devices,
+            "n_local": n_local,
+            "steps_per_task": S,
+            "updates_per_device": n_tasks * S,
+            "het": het_np,
+            "wcounts": per_device_wcounts,
+            "params": jax.tree.map(lambda v: v[0], res["params"]),
+            "params_fleet": res["params"],
+        })
+        if fspec is not None:
+            out["faults"] = {"spec": fspec,
+                             "rate_scale": fault_scale_np,
+                             "dead_chips": dead_np}
+        if obs_on:
+            from repro.obs.runlog import build_runlog, drift_stream
+
+            def _ps(a):
+                # Per-step stream (D, n_tasks, S) → (D, total).
+                return np.asarray(a).reshape(D, -1)
+
+            if in_graph:
+                occ = _ps(obs_streams["replay_occupancy"])
+            else:
+                occ = np.stack([sc.occupancy_stream() for sc in scheds])
+            cb = backend.spec.crossbar
+            drifting = (inputs[0].dev_state is not None and cb is not None
+                        and (getattr(cb, "drift_rate", 0.0) > 0
+                             or (het_np is not None
+                                 and "drift_rate" in het_np)))
+            drift = np.broadcast_to(
+                drift_stream(n_tasks * S, drifting=drifting),
+                (D, n_tasks * S))
+            out["runlog"] = build_runlog(
+                cadence=obs.cadence,
+                steps_per_task=scheds[0].steps_per_task,
+                loss=_ps(res["losses"]),
+                write_pulses=_ps(obs_streams["write_pulses"]),
+                dg_mag=_ps(obs_streams["dg_mag"]),
+                replay_occupancy=occ,
+                drift_ticks=drift,
+                task_acc=res["R_full"])
         if backend.tracker is not None:
-            backend.tracker.record_counts(counts, total_steps)
-
-    per_device = [_summarize_run(res["R_full"][i], res["baseline_row"][i],
-                                 res["losses"][i], baseline)
-                  for i in range(D)]
-    out: dict[str, Any] = dict(per_device[0])
-    out.update(_aggregate_seeds(per_device, seeds))
-    out["per_device"] = out.pop("per_seed")
-    out["device_seeds"] = out.pop("seeds")
-    out.update({
-        "compiled": True,
-        "fleet": fleet,
-        "n_devices": D,
-        "n_shards": n_shards,
-        "shard_devices": shard_devices,
-        "n_local": n_local,
-        "steps_per_task": S,
-        "updates_per_device": n_tasks * S,
-        "het": het_np,
-        "wcounts": per_device_wcounts,
-        "params": jax.tree.map(lambda v: v[0], res["params"]),
-        "params_fleet": res["params"],
-    })
-    if fspec is not None:
-        out["faults"] = {"spec": fspec,
-                         "rate_scale": fault_scale_np,
-                         "dead_chips": dead_np}
+            out["endurance"] = backend.tracker
+        if tele.enabled:
+            out["telemetry"] = tele
     out.update(times)
-    if obs_on:
-        from repro.obs.runlog import build_runlog, drift_stream
-
-        def _ps(a):
-            # Per-step stream (D, n_tasks, S) → (D, total).
-            return np.asarray(a).reshape(D, -1)
-
-        if in_graph:
-            occ = _ps(obs_streams["replay_occupancy"])
-        else:
-            occ = np.stack([sc.occupancy_stream() for sc in scheds])
-        cb = backend.spec.crossbar
-        drifting = (inputs[0].dev_state is not None and cb is not None
-                    and (getattr(cb, "drift_rate", 0.0) > 0
-                         or (het_np is not None
-                             and "drift_rate" in het_np)))
-        drift = np.broadcast_to(
-            drift_stream(n_tasks * S, drifting=drifting),
-            (D, n_tasks * S))
-        out["runlog"] = build_runlog(
-            cadence=obs.cadence,
-            steps_per_task=scheds[0].steps_per_task,
-            loss=_ps(res["losses"]),
-            write_pulses=_ps(obs_streams["write_pulses"]),
-            dg_mag=_ps(obs_streams["dg_mag"]),
-            replay_occupancy=occ,
-            drift_ticks=drift,
-            task_acc=res["R_full"])
-    if backend.tracker is not None:
-        out["endurance"] = backend.tracker
-    if tele.enabled:
-        out["telemetry"] = tele
     return out

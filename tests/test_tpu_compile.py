@@ -165,3 +165,61 @@ def test_kernels_compile_under_shard_map(topo, compiled_for_tpu):
         (D, *shape), jnp.float32, sharding=NamedSharding(mesh, P("fleet")))
     assert _compile(fn, fleet(B, T, K), fleet(K, H), fleet(H, H),
                     fleet(H)) == {"wbs_matmul", "wbs_miru_scan"}
+
+
+def test_fleet_program_compiles_on_analog_state(topo, compiled_for_tpu):
+    """``run_fleet``'s program on the conductance-domain substrate with
+    the ``mild`` profile, at the paper's widths: every crossbar access
+    reads the G⁺/G⁻ pairs with per-chip traced noise into the WBS kernel,
+    every update drifts and writes them, vmapped over each chip's
+    emulated chips and sharded over the described host's four chips."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.backends import get_backend
+    from repro.configs.m2ru_paper import PAPER_CONFIG
+    from repro.core.continual import (ReplaySpec, TrainerSpec,
+                                      _make_raw_steps)
+    from repro.data.synthetic import TaskData
+    from repro.fleet import FleetSpec
+    from repro.fleet.heterogeneity import (device_seeds, draw_heterogeneity,
+                                           overlay_device_states)
+    from repro.fleet.run import shard_fleet
+    from repro.scenarios.sweep import _build_seed_inputs, _make_run_fn
+
+    cfg = PAPER_CONFIG
+    rng = np.random.default_rng(0)
+    tasks = [TaskData(x_train=rng.random((64, 28, cfg.n_x), np.float32),
+                      y_train=rng.integers(0, 10, 64).astype(np.int32),
+                      x_test=rng.random((32, 28, cfg.n_x), np.float32),
+                      y_test=rng.integers(0, 10, 32).astype(np.int32),
+                      task_id=t) for t in range(2)]
+    trainer = TrainerSpec(algo="dfa", batch_size=32)
+    backend = get_backend("analog_state", use_kernel=True)
+    fleet = FleetSpec(n_devices=8, het_profile="mild", seed=1)
+    _, _, opt = _make_raw_steps(cfg, trainer, backend)
+    one, _ = _build_seed_inputs(cfg, dataclasses.replace(trainer, seed=3),
+                                ReplaySpec(), backend, tasks, opt)
+    stacked = jax.tree.map(lambda a: jnp.stack([a] * fleet.n_devices),
+                           one.as_arrays())
+    pairs = overlay_device_states(backend, stacked[0], device_seeds(fleet),
+                                  draw_heterogeneity(fleet))
+    stacked = stacked[:2] + (pairs,) + stacked[3:]
+    run = _make_run_fn(cfg, trainer, backend, len(tasks), one.xs.shape[1],
+                       False, True, flush=lambda: None)
+    mesh = Mesh(np.array(topo.devices), ("fleet",))
+
+    def shaped(spec):
+        sharding = NamedSharding(mesh, spec)
+        return lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                              sharding=sharding)
+
+    evals = (jnp.stack([t.x_test for t in tasks]),
+             jnp.stack([t.y_test for t in tasks]))
+    args = jax.tree.map(shaped(P("fleet")), stacked) \
+        + jax.tree.map(shaped(P()), evals)
+    fn = shard_fleet(run, mesh, "fleet")
+    assert compiled_kernels(fn.lower(*args).compile().as_text()) \
+        == {"wbs_matmul"}
