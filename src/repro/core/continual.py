@@ -628,7 +628,13 @@ def build_batch_schedule(trainer: TrainerSpec, replay: ReplaySpec,
     """Materialize the replay-mixed batch stream ``run_continual`` trains
     on, consuming the host RNG streams (epoch shuffle, replay-policy
     sampler, stochastic quantizer) in exactly the order the training
-    loop does. Slot selection routes through the
+    loop does. The quantizer is deferred: the walk stages each batch's
+    admissions and leaves each replay row as the version of the write it
+    reads (:meth:`ReplayBuffer.stage_batch`/``sample_staged``), and one
+    :meth:`ReplayBuffer.commit` at the end quantizes every write in one
+    dispatch and fills the replay rows — bit-identical to quantizing at
+    each batch, at one device round trip a schedule instead of one a
+    batch. Slot selection routes through the
     :mod:`repro.replay` policy named by ``replay.resolved_policy``
     (``reservoir`` reproduces the pre-policy schedule bit-for-bit —
     pinned by the golden hash in tests/test_determinism.py).
@@ -675,6 +681,8 @@ def build_batch_schedule(trainer: TrainerSpec, replay: ReplaySpec,
                               seed=trainer.seed, policy=policy)
     host_rng = np.random.default_rng(trainer.seed + 1)
 
+    # (task, batch, versions) of each replay tail, filled after commit.
+    replay_rows: list[tuple[int, int, np.ndarray]] = []
     xs_all: list[np.ndarray] = []
     ys_all: list[np.ndarray] = []
     occ_all: list[np.ndarray] = []
@@ -718,9 +726,11 @@ def build_batch_schedule(trainer: TrainerSpec, replay: ReplaySpec,
                         and replay.ratio > 0):
                     n_rep = int(round(bs * replay.ratio))
                     if n_rep > 0:
-                        xr, yr = buffer.sample(host_rng, n_rep)
-                        xb = np.concatenate([xb[:bs - n_rep],
-                                             xr.reshape(-1, T, F)])
+                        ver, yr = buffer.sample_staged(host_rng, n_rep)
+                        replay_rows.append((t, len(xs_t), ver))
+                        xb = np.concatenate([
+                            xb[:bs - n_rep],
+                            np.zeros((n_rep, T, F), np.float32)])
                         yb = np.concatenate([yb[:bs - n_rep], yr])
                         # Rehearsal rows are real work, replayed at
                         # full T (the buffer stores fixed-shape rows).
@@ -736,9 +746,9 @@ def build_batch_schedule(trainer: TrainerSpec, replay: ReplaySpec,
                     # the historical call shape stays byte-for-byte.
                     mask_kw = ({"valid": rv[:n_fresh]}
                                if pad is not None else {})
-                    buffer.add_batch(xb[:n_fresh], yb[:n_fresh],
-                                     task_ids=np.full(n_fresh, t),
-                                     **mask_kw)
+                    buffer.stage_batch(xb[:n_fresh], yb[:n_fresh],
+                                       task_ids=np.full(n_fresh, t),
+                                       **mask_kw)
                 xs_t.append(xb)
                 ys_t.append(yb)
                 occ_t.append(buffer.size if buffer is not None else 0)
@@ -753,6 +763,10 @@ def build_batch_schedule(trainer: TrainerSpec, replay: ReplaySpec,
                       else np.zeros((0, bs), bool))
         ln_all.append(np.stack(ln_t) if ln_t
                       else np.zeros((0, bs), np.int32))
+    if buffer is not None:
+        table = buffer.commit()
+        for t, b, ver in replay_rows:
+            xs_all[t][b, bs - len(ver):] = buffer.dequantize(table[ver])
     return BatchSchedule(x=xs_all, y=ys_all,
                          replay_traffic=dict(buffer.traffic)
                          if buffer is not None else {},

@@ -183,6 +183,23 @@ def _quantize_many(xs: jax.Array, keys: jax.Array, n_bits: int) -> jax.Array:
     return jax.vmap(lambda x, k: stochastic_quantize(x, k, n_bits))(xs, keys)
 
 
+@functools.partial(jax.jit, static_argnums=3)
+def _quantize_log(key: jax.Array, xs: jax.Array, n: jax.Array, n_bits: int
+                  ) -> tuple[jax.Array, jax.Array]:
+    """The first ``n`` rows of ``xs`` quantized under the sequential key
+    chain, in one dispatch: (key advanced ``n`` steps, codes). The chain
+    stops advancing at ``n`` — its first ``n`` subkeys and the returned
+    key are bit-identical to ``_split_chain(key, n)`` — so ``xs`` may be
+    padded to a fixed length with rows whose codes are discarded, and
+    one compile serves every ``n`` up to it."""
+    def body(k, i):
+        nk, sub = jax.random.split(k)
+        return jnp.where(i < n, nk, k), sub
+
+    key, subs = jax.lax.scan(body, key, jnp.arange(xs.shape[0]))
+    return key, _quantize_many(xs, subs, n_bits)
+
+
 # ---------------------------------------------------------------------------
 # Reservoir sampler + replay buffer
 # ---------------------------------------------------------------------------
@@ -232,6 +249,17 @@ class ReplayBuffer:
     the paper's §IV-A hardware bit-for-bit — identical sampler seed
     derivation, identical host-RNG consumption — so schedules built
     through the policy layer hash to the pre-refactor golden digest.
+
+    Two ways to write. :meth:`add` / :meth:`add_batch` quantize at once
+    (one device round trip per call) and :meth:`sample` reads codes.
+    :meth:`stage_batch` / :meth:`sample_staged` / :meth:`commit` defer
+    the quantizer: the policy and the labels advance on the host as
+    before, the admitted raw rows wait in a write log, samples come back
+    as *versions* (which write holds the slot), and :meth:`commit`
+    quantizes the whole log in one dispatch and resolves them — the
+    same codes, key chain and final state as the eager calls, bit for
+    bit (tests/test_replay.py). ``build_batch_schedule`` takes the
+    deferred path: one round trip a schedule instead of one a batch.
     """
 
     def __init__(self, capacity: int, feature_shape: tuple[int, ...],
@@ -268,6 +296,15 @@ class ReplayBuffer:
         # traffic to a run's telemetry exactly once (run_continual and
         # the compiled sweep build/discard schedules at different times).
         self.traffic: dict[str, int] = {}
+        # The write log of the staged path: raw admitted rows, the rows
+        # offered since the last commit (the padded length of its one
+        # dispatch) and each slot's version — a row of the table
+        # ``commit`` returns: slot i's committed codes (row i) or staged
+        # write j (row capacity + j).
+        self._log: list[np.ndarray] = []
+        self._n_log = 0
+        self._n_offered = 0
+        self._version = np.arange(capacity)
 
     # ------------------------------------------------------------------
     def _meter(self, *, reads: int = 0, writes: int = 0) -> None:
@@ -289,8 +326,30 @@ class ReplayBuffer:
         if self._telemetry is not None and self._telemetry.enabled:
             self._telemetry.record(deltas)
 
+    def _require_committed(self) -> None:
+        if self._n_log:
+            raise RuntimeError(f"{self._n_log} staged writes pending: "
+                               "commit() before an eager add or sample")
+
+    def _select(self, ys, task_ids, valid) -> tuple[list[int], list[int]]:
+        """Offer each valid row to the policy: the (slot, row) pairs of
+        the admissions, in order."""
+        slots: list[int] = []
+        keep: list[int] = []
+        for i in range(len(ys)):
+            if valid is not None and not valid[i]:
+                continue
+            tid = int(task_ids[i]) if task_ids is not None else 0
+            slot = self.policy.select_insert(int(ys[i]), tid)
+            if slot is None:
+                continue
+            slots.append(slot)
+            keep.append(i)
+        return slots, keep
+
     def add(self, x: np.ndarray, y: int, task_id: int = 0) -> bool:
         """Offer one (features∈[0,1], label) example to the policy."""
+        self._require_committed()
         slot = self.policy.select_insert(int(y), int(task_id))
         if slot is None:
             return False
@@ -310,23 +369,15 @@ class ReplayBuffer:
         :meth:`add` calls bit-for-bit (same key chain, same quantizer
         draws — asserted in tests/test_replay.py), but all accepted
         examples are quantized in one vmapped dispatch instead of one
-        jax call per example — the schedule-building hot path.
+        jax call per example. (A schedule stages its batches instead —
+        :meth:`stage_batch` — and pays one dispatch in all.)
 
         ``valid`` (a (B,) bool mask) gates padded rows out entirely:
         an invalid row is never offered to the policy and consumes no
         sampler or quantizer RNG, so a zero-padded batch leaves the
         buffer in exactly the state the unpadded batch would."""
-        slots: list[int] = []
-        keep: list[int] = []
-        for i in range(len(xs)):
-            if valid is not None and not valid[i]:
-                continue
-            tid = int(task_ids[i]) if task_ids is not None else 0
-            slot = self.policy.select_insert(int(ys[i]), tid)
-            if slot is None:
-                continue
-            slots.append(slot)
-            keep.append(i)
+        self._require_committed()
+        slots, keep = self._select(ys, task_ids, valid)
         if not slots:
             return 0
         # The exact sequential key chain self._qkey would have walked,
@@ -349,12 +400,72 @@ class ReplayBuffer:
         (uniform over the occupied prefix under ``reservoir``/``ring``;
         stratified under the partitioned policies). Dequantizes on the
         paper's 1/2^n scale — see :func:`round_trip_bound`."""
+        self._require_committed()
         if self.size == 0:
             raise ValueError("empty replay buffer")
         idx = np.asarray(self.policy.select_sample(rng, batch))
-        feats = self._feat[idx].astype(np.float32) / (2.0 ** self.n_bits)
         self._meter(reads=batch)
-        return feats, self._label[idx]
+        return self.dequantize(self._feat[idx]), self._label[idx]
+
+    def dequantize(self, codes: np.ndarray) -> np.ndarray:
+        """Host codes → float32 features on the paper's 1/2^n scale."""
+        return codes.astype(np.float32) / (2.0 ** self.n_bits)
+
+    def stage_batch(self, xs: np.ndarray, ys: np.ndarray,
+                    task_ids=None, valid=None) -> int:
+        """:meth:`add_batch` with the quantizer deferred to
+        :meth:`commit`. The policy's draws, the labels, the occupancy
+        and the metering happen now, exactly as in :meth:`add_batch`;
+        the admitted raw rows go to the write log and each written slot
+        takes the write's version. Touches no device."""
+        slots, keep = self._select(ys, task_ids, valid)
+        self._n_offered += (len(ys) if valid is None
+                            else int(np.count_nonzero(valid)))
+        for slot, i in zip(slots, keep):
+            self._version[slot] = self.capacity + self._n_log
+            self._label[slot] = int(ys[i])
+            self._n_log += 1
+        if slots:
+            self._log.append(xs[keep])
+        self.size = self.policy.occupancy
+        self._meter(writes=len(slots))
+        return len(slots)
+
+    def sample_staged(self, rng: np.random.Generator, batch: int
+                      ) -> tuple[np.ndarray, np.ndarray]:
+        """:meth:`sample` under staged writes: the same policy draw and
+        metering, but each picked slot comes back as its current version
+        — a row of the table :meth:`commit` returns — not as features."""
+        if self.size == 0:
+            raise ValueError("empty replay buffer")
+        idx = np.asarray(self.policy.select_sample(rng, batch))
+        self._meter(reads=batch)
+        return self._version[idx], self._label[idx]
+
+    def commit(self) -> np.ndarray:
+        """Quantize every staged write in one dispatch and store the
+        codes. The dispatch is padded to the rows offered since the last
+        commit, a count fixed by the stream's shape and not by the
+        policy's draws, so seeds of one stream share its compile.
+
+        Returns the code table the staged versions index: the codes the
+        slots held before staging (rows ``0..capacity-1``), then one row
+        per staged write in admission order."""
+        n = self._n_log
+        codes = np.zeros((0, *self._feat.shape[1:]), self._feat.dtype)
+        if n:
+            xs = np.concatenate(self._log)
+            xs = np.concatenate([xs, np.zeros(
+                (self._n_offered - n, *xs.shape[1:]), xs.dtype)])
+            with span("replay.quantize", rows=n):
+                self._qkey, q = _quantize_log(self._qkey, jnp.asarray(xs),
+                                              n, self.n_bits)
+                codes = np.asarray(q)[:n]
+        table = np.concatenate([self._feat, codes])
+        self._feat[:] = table[self._version]
+        self._version = np.arange(self.capacity)
+        self._log, self._n_log, self._n_offered = [], 0, 0
+        return table
 
     @property
     def nbytes(self) -> int:

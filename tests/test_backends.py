@@ -297,13 +297,13 @@ def test_run_continual_rejects_mixed_legacy_and_new():
 def test_task0_buffer_seeded_from_full_batches(monkeypatch):
     from repro.core import replay as replay_mod
     offered = []
-    orig = replay_mod.ReplayBuffer.add_batch
+    orig = replay_mod.ReplayBuffer.stage_batch
 
     def spy(self, xs, ys, task_ids=None):
         offered.append(len(xs))
         return orig(self, xs, ys, task_ids=task_ids)
 
-    monkeypatch.setattr(replay_mod.ReplayBuffer, "add_batch", spy)
+    monkeypatch.setattr(replay_mod.ReplayBuffer, "stage_batch", spy)
     from repro.data.synthetic import make_permuted_tasks
     tasks = make_permuted_tasks(0, n_tasks=2, n_train=64, n_test=32)
     run_continual(MiRUConfig(n_x=28, n_h=8, n_y=10),

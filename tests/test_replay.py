@@ -256,3 +256,160 @@ def test_replay_empty_raises():
     buf = ReplayBuffer(capacity=4, feature_shape=(2,))
     with pytest.raises(ValueError):
         buf.sample(np.random.default_rng(0), 1)
+
+
+# ---------------------------------------------------------------------------
+# The schedule's deferred quantizer
+# ---------------------------------------------------------------------------
+
+def _small_stream(seed, n_tasks=3, n_train=40, t=6, f=5, n_cls=4):
+    rng = np.random.default_rng(seed)
+    from repro.data.synthetic import TaskData
+    return [TaskData(x_train=rng.random((n_train, t, f)).astype(np.float32),
+                     y_train=rng.integers(0, n_cls, n_train).astype(np.int32),
+                     x_test=rng.random((8, t, f)).astype(np.float32),
+                     y_test=rng.integers(0, n_cls, 8).astype(np.int32),
+                     task_id=k)
+            for k in range(n_tasks)]
+
+
+def _eager_schedule(trainer, replay, tasks, keep_partial):
+    """The batch stream with the quantizer run at every batch — eager
+    ``add_batch``/``sample`` on the buffer, in the schedule's RNG order.
+    Returns (xs, ys, occupancy) per task and the buffer."""
+    from repro.core.continual import _stream_context
+    from repro.replay import make_policy
+    T, F = tasks[0].x_train.shape[1:]
+    bs = trainer.batch_size
+    buf = ReplayBuffer(replay.capacity, (T, F), n_bits=replay.bits,
+                       seed=trainer.seed,
+                       policy=make_policy(replay.resolved_policy,
+                                          replay.capacity, seed=trainer.seed,
+                                          **_stream_context(tasks)))
+    host_rng = np.random.default_rng(trainer.seed + 1)
+    out = []
+    for t, task in enumerate(tasks):
+        n = task.x_train.shape[0]
+        xs_t, ys_t, occ_t = [], [], []
+        order = host_rng.permutation(n)
+        for s in range(0, n + 1 if keep_partial else n - bs + 1, bs):
+            idx = order[s:s + bs]
+            if len(idx) == 0:
+                continue
+            pad = bs - len(idx)
+            xb = np.concatenate([task.x_train[idx],
+                                 np.zeros((pad, T, F), np.float32)])
+            yb = np.concatenate([task.y_train[idx], np.zeros(pad, np.int32)])
+            valid = np.arange(bs) < len(idx)
+            n_rep = 0
+            if t > 0 and buf.size > 0:
+                n_rep = int(round(bs * replay.ratio))
+                xr, yr = buf.sample(host_rng, n_rep)
+                xb = np.concatenate([xb[:bs - n_rep], xr])
+                yb = np.concatenate([yb[:bs - n_rep], yr])
+            buf.add_batch(xb[:bs - n_rep], yb[:bs - n_rep],
+                          task_ids=np.full(bs - n_rep, t),
+                          valid=valid[:bs - n_rep])
+            xs_t.append(xb)
+            ys_t.append(yb)
+            occ_t.append(buf.size)
+        out.append((np.stack(xs_t), np.stack(ys_t), np.asarray(occ_t)))
+    return out, buf
+
+
+@pytest.mark.parametrize("policy,seed,keep_partial", [
+    ("reservoir", 0, False), ("reservoir", 1, False),
+    ("reservoir", 2, False), ("class_balanced", 0, False),
+    ("class_balanced", 1, False), ("class_balanced", 2, False),
+    ("reservoir", 3, True)])
+def test_deferred_schedule_bit_identical_to_eager_batches(
+        monkeypatch, policy, seed, keep_partial):
+    """build_batch_schedule stages every batch and quantizes once at the
+    end; the batches, occupancy, metered traffic and the buffer it
+    leaves must equal quantizing at each batch, bit for bit — on a
+    partitioned policy and on a padded stream with a partial last batch
+    too."""
+    from repro.core.continual import (ReplaySpec, TrainerSpec,
+                                      build_batch_schedule)
+    from repro.data.ragged import PadPolicy
+    tasks = _small_stream(seed)
+    trainer = TrainerSpec(algo="dfa", epochs_per_task=1, batch_size=16,
+                          seed=seed)
+    replay = ReplaySpec(capacity=24, policy=policy)
+    committed = []
+    commit = ReplayBuffer.commit
+
+    def spy(self):
+        committed.append(self)
+        return commit(self)
+
+    monkeypatch.setattr(ReplayBuffer, "commit", spy)
+    sched = build_batch_schedule(
+        trainer, replay, tasks,
+        pad=PadPolicy(last_batch="pad") if keep_partial else None)
+    eager, buf = _eager_schedule(trainer, replay, tasks, keep_partial)
+    for (xs, ys, occ), x, y, o in zip(eager, sched.x, sched.y,
+                                      sched.occupancy):
+        np.testing.assert_array_equal(x, xs)
+        np.testing.assert_array_equal(y, ys)
+        np.testing.assert_array_equal(o, occ)
+    assert sched.replay_traffic == buf.traffic
+    deferred, = committed
+    np.testing.assert_array_equal(deferred._feat, buf._feat)
+    np.testing.assert_array_equal(deferred._label, buf._label)
+    np.testing.assert_array_equal(np.asarray(deferred._qkey),
+                                  np.asarray(buf._qkey))
+    if keep_partial:
+        assert sched.steps_per_task == [3, 3, 3]
+
+
+def test_deferred_quantize_one_compile_and_one_span_per_schedule():
+    """Seeds admit different counts, but the deferred dispatch is padded
+    to the rows offered — a count of the stream's shape — so the second
+    seed compiles nothing; each schedule makes one ``replay.quantize``
+    span whose ``rows`` is its admissions."""
+    from repro.core import replay as replay_mod
+    from repro.core.continual import (ReplaySpec, TrainerSpec,
+                                      build_batch_schedule)
+    from repro.obs import Tracer, activate
+    from repro.telemetry import meters as M
+    tasks = _small_stream(0, t=7)               # a shape no other test uses
+    replay = ReplaySpec(capacity=24)
+    tr = Tracer()
+    sizes, writes = [], []
+    with activate(tr):
+        for seed in (2, 3):                     # 43 and 51 admissions
+            sched = build_batch_schedule(
+                TrainerSpec(algo="dfa", epochs_per_task=1, batch_size=16,
+                            seed=seed), replay, tasks)
+            sizes.append(replay_mod._quantize_log._cache_size())
+            writes.append(sched.replay_traffic[M.REPLAY_WRITES])
+    assert writes[0] != writes[1]
+    assert sizes[1] == sizes[0]
+    spans = [e for e in tr.events()
+             if e["ph"] == "X" and e["name"] == "replay.quantize"]
+    assert [e["args"]["rows"] for e in spans] == writes
+
+
+def test_staged_writes_guard_eager_calls_and_index_committed_codes():
+    """An eager add or sample with staged writes pending would read
+    stale codes: it raises. The table a commit returns starts with the
+    codes committed before staging, and a version staged after a commit
+    points at a committed row."""
+    buf = ReplayBuffer(capacity=4, feature_shape=(3,), n_bits=4, seed=1)
+    rng = np.random.default_rng(0)
+    buf.add_batch(rng.random((4, 3)).astype(np.float32), np.arange(4))
+    before = buf._feat.copy()
+    n = buf.stage_batch(rng.random((6, 3)).astype(np.float32), np.arange(6))
+    with pytest.raises(RuntimeError, match="commit"):
+        buf.sample(rng, 1)
+    with pytest.raises(RuntimeError, match="commit"):
+        buf.add_batch(rng.random((1, 3)).astype(np.float32), [0])
+    table = buf.commit()
+    assert table.shape == (4 + n, 3)
+    np.testing.assert_array_equal(table[:4], before)
+    ver, _ = buf.sample_staged(np.random.default_rng(1), 8)
+    assert (ver < 4).all()
+    after = buf.commit()                        # nothing staged: no write
+    np.testing.assert_array_equal(after, buf._feat)
+    np.testing.assert_array_equal(after[ver], buf._feat[ver])
