@@ -283,89 +283,37 @@ def _make_raw_steps(cfg: MiRUConfig, trainer: TrainerSpec,
     write path — the backend supplies the substrate-specific pieces.
     ``run_continual`` jits these per call; the compiled scenario sweep
     (`repro.scenarios.sweep`) traces the same functions inside its
-    scan-over-tasks, which is what keeps the two paths bit-comparable."""
+    scan-over-tasks, which is what keeps the two paths bit-comparable.
+
+    ``train_step(params, opt_state, key, x, y, dev_state, valid=None,
+    lengths=None)`` and ``evaluate(params, key, x, y, dev_state,
+    valid=None, lengths=None)`` take the padding masks of a ragged
+    schedule (:mod:`repro.data.ragged`) as optional inputs: ``valid`` is
+    the (B,) row mask (padded rows contribute nothing to loss, gradients
+    or accuracy), ``lengths`` the (B,) true sequence lengths (readout and
+    DFA error at each row's own last step). Without them the trace is the
+    unmasked one. The masked reductions divide by Σvalid with the same
+    ``lax.div`` the unmasked mean uses and multiply by exactly 0.0/1.0,
+    so an all-valid, all-full-length batch computes the same values —
+    equal to float32 ulp-level (XLA may fuse the runtime mask multiplies
+    into the reductions and reassociate by ±1 ulp; see
+    :mod:`repro.data.ragged`)."""
     opt = adam(trainer.adam_lr)
 
-    def fwd(p, c, xs, k, st):
-        return miru_forward_device(p, c, xs, k, backend, state=st,
-                                   fused=trainer.fused_recurrence)
-
-    if trainer.algo == "adam":
-        def train_step(params, opt_state, key, x, y, dev_state):
-            k_fwd, k_wr = jax.random.split(key)
-
-            def loss_fn(p):
-                logits, _ = fwd(p, cfg, x, k_fwd, dev_state)
-                return softmax_cross_entropy(logits, y)
-
-            loss, grads = jax.value_and_grad(loss_fn)(params)
-            updates, opt_state_ = opt.update(grads, opt_state, params)
-            params, applied, dev_state = backend.device_apply_update(
-                params, updates, k_wr, state=dev_state)
-            backend.telemetry.emit_pending()
-            return params, opt_state_, loss, applied, dev_state
-
-    elif trainer.algo == "dfa":
-        def train_step(params, opt_state, key, x, y, dev_state):
-            psi = opt_state["psi"]
-            k_fwd, k_wr = jax.random.split(key)
-            loss, grads = dfa_mod.dfa_grads(
-                params, psi, cfg, x, y,
-                forward_fn=lambda p, c, xs: fwd(p, c, xs, k_fwd,
-                                                dev_state))
-            # ζ-sparsify, scale per layer, hand the write to the device.
-            updates = dfa_mod.scaled_sparse_updates(
-                grads, trainer.lr, trainer.kwta_keep_frac,
-                trainer.hidden_lr_scale)
-            params, applied, dev_state = backend.device_apply_update(
-                params, updates, k_wr, state=dev_state)
-            backend.telemetry.emit_pending()
-            return params, opt_state, loss, applied, dev_state
-
-    else:
-        raise ValueError(f"unknown trainer algo {trainer.algo!r}; "
-                         f"expected 'adam' or 'dfa'")
-
-    def evaluate(params, key, x, y, dev_state):
-        logits, _ = fwd(params, cfg, x, key, dev_state)
-        backend.telemetry.emit_pending()
-        return acc_fn(logits, y)
-
-    return train_step, evaluate, opt
-
-
-def _make_masked_steps(cfg: MiRUConfig, trainer: TrainerSpec,
-                       backend: DeviceBackend):
-    """The masked-reduction twins of :func:`_make_raw_steps` for padded
-    ragged schedules (:mod:`repro.data.ragged`).
-
-    ``train_step(params, opt_state, key, x, y, dev_state, valid,
-    lengths)`` and ``evaluate(params, key, x, y, dev_state, valid,
-    lengths)``: ``valid`` is the (B,) row mask (padded rows contribute
-    nothing to loss, gradients or accuracy), ``lengths`` the (B,) true
-    sequence lengths (readout and DFA error at each row's own last
-    step). Every reduction divides by Σvalid with the same ``lax.div``
-    the unmasked mean uses, and masks multiply by exactly 0.0/1.0, so
-    an all-valid, all-full-length batch computes the same values as the
-    raw steps — equal to float32 ulp-level (XLA may fuse the runtime
-    mask multiplies into the reductions and reassociate by ±1 ulp; see
-    :mod:`repro.data.ragged`), the tolerance benchmarks/data_bench.py
-    gates.
-    """
-    opt = adam(trainer.adam_lr)
-
-    def fwd(p, c, xs, k, st, lengths):
+    def fwd(p, c, xs, k, st, lengths=None):
         return miru_forward_device(p, c, xs, k, backend, state=st,
                                    fused=trainer.fused_recurrence,
                                    lengths=lengths)
 
     if trainer.algo == "adam":
-        def train_step(params, opt_state, key, x, y, dev_state, valid,
-                       lengths):
+        def train_step(params, opt_state, key, x, y, dev_state,
+                       valid=None, lengths=None):
             k_fwd, k_wr = jax.random.split(key)
 
             def loss_fn(p):
                 logits, _ = fwd(p, cfg, x, k_fwd, dev_state, lengths)
+                if valid is None:
+                    return softmax_cross_entropy(logits, y)
                 m = valid.astype(logits.dtype)
                 logz = jax.nn.logsumexp(logits, axis=-1)
                 ll = jnp.take_along_axis(logits, y[..., None],
@@ -381,8 +329,8 @@ def _make_masked_steps(cfg: MiRUConfig, trainer: TrainerSpec,
             return params, opt_state_, loss, applied, dev_state
 
     elif trainer.algo == "dfa":
-        def train_step(params, opt_state, key, x, y, dev_state, valid,
-                       lengths):
+        def train_step(params, opt_state, key, x, y, dev_state,
+                       valid=None, lengths=None):
             psi = opt_state["psi"]
             k_fwd, k_wr = jax.random.split(key)
             loss, grads = dfa_mod.dfa_grads(
@@ -390,6 +338,7 @@ def _make_masked_steps(cfg: MiRUConfig, trainer: TrainerSpec,
                 forward_fn=lambda p, c, xs: fwd(p, c, xs, k_fwd,
                                                 dev_state, lengths),
                 row_valid=valid, lengths=lengths)
+            # ζ-sparsify, scale per layer, hand the write to the device.
             updates = dfa_mod.scaled_sparse_updates(
                 grads, trainer.lr, trainer.kwta_keep_frac,
                 trainer.hidden_lr_scale)
@@ -402,9 +351,11 @@ def _make_masked_steps(cfg: MiRUConfig, trainer: TrainerSpec,
         raise ValueError(f"unknown trainer algo {trainer.algo!r}; "
                          f"expected 'adam' or 'dfa'")
 
-    def evaluate(params, key, x, y, dev_state, valid, lengths):
+    def evaluate(params, key, x, y, dev_state, valid=None, lengths=None):
         logits, _ = fwd(params, cfg, x, key, dev_state, lengths)
         backend.telemetry.emit_pending()
+        if valid is None:
+            return acc_fn(logits, y)
         m = valid.astype(jnp.float32)
         ok = (jnp.argmax(logits, axis=-1) == y).astype(jnp.float32)
         return jnp.sum(ok * m) / jnp.maximum(jnp.sum(m), 1.0)
@@ -838,20 +789,19 @@ def run_continual(cfg: MiRUConfig,
     ``obs=None`` (the default) adds nothing to the loop.
 
     ``pad`` is a :class:`repro.data.ragged.PadPolicy` for ragged task
-    streams: tasks are padded onto one bucketed shape and the loop runs
-    the masked step/eval twins (:func:`_make_masked_steps`) over the
-    masked schedule — or, when nothing is actually ragged and
-    ``pad.force`` is off, the exact unmasked program. The loop walks
-    only real steps (the compiled sweep's step-axis padding does not
-    exist here), on the same PRNG chain, which is what keeps the two
-    paths bit-comparable on padded streams too.
+    streams: tasks are padded onto one bucketed shape and the loop hands
+    the masked schedule's row masks and true lengths to the one step and
+    eval (:func:`_make_raw_steps`) — or, when nothing is actually ragged
+    and ``pad.force`` is off, no masks: the exact unmasked program. The
+    loop walks only real steps (the compiled sweep's step-axis padding
+    does not exist here), on the same PRNG chain, which is what keeps
+    the two paths bit-comparable on padded streams too.
     """
     trainer, rspec, backend = _resolve_specs(spec, replay, device)
 
     from repro.replay import get_policy_class, ingraph_init
     in_graph = get_policy_class(rspec.resolved_policy).in_graph
     masked = False
-    ev_valid = ev_len = None
     if pad is not None:
         from repro.data.ragged import eval_masks, pad_tasks
         if in_graph:
@@ -872,12 +822,20 @@ def run_continual(cfg: MiRUConfig,
     if pad is not None:
         from repro.data.ragged import needs_masked_program
         masked = needs_masked_program(pad, eval_padded, schedule)
-        if masked:
-            ev_valid, ev_len = eval_masks(tasks)
+    # The masks reach the step and the eval only where the run needs
+    # them; without them both trace the unmasked program.
+    ev_masks = [(None, None)] * len(tasks)
+    if masked:
+        ev_masks = [tuple(map(jnp.asarray, m))
+                    for m in zip(*eval_masks(tasks))]
 
-    raw_train, raw_eval, opt = (_make_masked_steps if masked
-                                else _make_raw_steps)(cfg, trainer,
-                                                      backend)
+    def step_masks(t, s):
+        if not masked:
+            return None, None
+        return (jnp.asarray(schedule.row_valid[t][s]),
+                jnp.asarray(schedule.lengths[t][s]))
+
+    raw_train, raw_eval, opt = _make_raw_steps(cfg, trainer, backend)
     if trainer.algo == "adam":
         opt_state = opt.init(params)
     else:
@@ -925,18 +883,12 @@ def run_continual(cfg: MiRUConfig,
                     jnp.asarray(schedule.x[t][s]),
                     jnp.asarray(schedule.y[t][s]), dev_state, rstate,
                     replay_on)
-            elif masked:
-                params, opt_state, loss, applied, dev_state = train_step(
-                    params, opt_state, k_step,
-                    jnp.asarray(schedule.x[t][s]),
-                    jnp.asarray(schedule.y[t][s]), dev_state,
-                    jnp.asarray(schedule.row_valid[t][s]),
-                    jnp.asarray(schedule.lengths[t][s]))
             else:
                 params, opt_state, loss, applied, dev_state = train_step(
                     params, opt_state, k_step,
                     jnp.asarray(schedule.x[t][s]),
-                    jnp.asarray(schedule.y[t][s]), dev_state)
+                    jnp.asarray(schedule.y[t][s]), dev_state,
+                    *step_masks(t, s))
             losses.append(float(loss))
             if obs_on:
                 pu, dg, oc = stats_fn(applied, rstate)
@@ -946,15 +898,10 @@ def run_continual(cfg: MiRUConfig,
                 obs_occ.append(np.asarray(oc))
             backend.record_endurance(applied)
         key, k_eval = jax.random.split(key)
-        if masked:
-            for i, task in enumerate(tasks[:t + 1]):
-                R[t, i] = float(evaluate(
-                    params, k_eval, jnp.asarray(task.x_test),
-                    jnp.asarray(task.y_test), dev_state,
-                    jnp.asarray(ev_valid[i]), jnp.asarray(ev_len[i])))
-        else:
-            R[t, :t + 1] = evaluate_tasks(evaluate, params, k_eval,
-                                          tasks, t, dev_state)
+        for i, task in enumerate(tasks[:t + 1]):
+            R[t, i] = float(evaluate(
+                params, k_eval, jnp.asarray(task.x_test),
+                jnp.asarray(task.y_test), dev_state, *ev_masks[i]))
 
     out: dict[str, Any] = {
         "R": R,

@@ -22,15 +22,16 @@ Three granularities of padding, each with its own mask:
             step axis with no-op steps (``step_valid``) whose results
             are discarded by the carry select.
 
-The hard contract: with a policy attached but nothing actually ragged,
-:func:`repro.scenarios.sweep.run_compiled` builds the exact pre-refactor
-program — bitwise-identical R/params/losses/telemetry, gated in
-benchmarks/data_bench.py. The masked program (``force=True`` or real
-raggedness) is a *different* compiled program; it is held to the repo's
-established loop-vs-compiled standard (R matrices exactly equal, losses
-within float32 ulp-level tolerance) and agrees with the unmasked
-program on aligned streams at the same ulp level — XLA fuses the
-runtime validity-mask multiplies into the reductions, which legally
+The masks are optional inputs of one training step and one compiled
+run. The hard contract: with a policy attached but nothing actually
+ragged, :func:`repro.scenarios.sweep.run_compiled` passes no masks and
+runs the unmasked trace — bitwise-identical R/params/losses/telemetry,
+gated in benchmarks/data_bench.py. With masks (``force=True`` or real
+raggedness) the same run traces a *different* program; it is held to
+the repo's established loop-vs-compiled standard (R matrices exactly
+equal, losses within float32 ulp-level tolerance) and agrees with the
+unmasked program on aligned streams at the same ulp level — XLA fuses
+the runtime validity-mask multiplies into the reductions, which legally
 reassociates the accumulation by ±1 ulp, so exact bit-equality across
 *different programs* is not promised (only across runs of the same
 program, which stay deterministic).

@@ -1,7 +1,7 @@
 """Sharded fleet runner: one compiled program, a population of chips.
 
 :func:`run_fleet` wraps the exact per-seed program that
-:func:`repro.scenarios.sweep.run_compiled` builds (`_build_seed_inputs`
+:func:`repro.scenarios.sweep.run_compiled` builds (`_prepare_seeds`
 → `_make_run_fn`), lifts it over a device axis with ``vmap``, and
 shards that axis across the host's accelerator mesh with ``shard_map``
 — the same mesh/PartitionSpec idiom as :mod:`repro.distributed`, but
@@ -48,8 +48,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.backends import DeviceBackend, get_backend
-from repro.core.continual import (ReplaySpec, TrainerSpec,
-                                  _ingraph_replay_traffic, _make_raw_steps)
+from repro.core.continual import ReplaySpec, TrainerSpec
 from repro.data.pipeline import shard_tasks
 from repro.data.synthetic import TaskData
 from repro.fleet.heterogeneity import (FleetSpec, device_seeds,
@@ -59,9 +58,10 @@ from repro.fleet.heterogeneity import (FleetSpec, device_seeds,
                                        overlay_fault_states)
 from repro.obs.tracer import activate, span
 from repro.replay import get_policy_class
-from repro.scenarios.sweep import (_aggregate_seeds, _build_seed_inputs,
-                                   _compile_and_run, _make_run_fn,
-                                   _program_key, _summarize_run)
+from repro.scenarios.sweep import (_aggregate_seeds, _compile_and_run,
+                                   _credit_writes, _make_run_fn,
+                                   _prepare_seeds, _program_key,
+                                   _summarize_run)
 
 __all__ = ["run_fleet", "fleet_shard_count", "shard_fleet"]
 
@@ -164,38 +164,24 @@ def run_fleet(cfg, spec: TrainerSpec, tasks: list[TaskData],
         raise ValueError("run_fleet needs shape-uniform eval sets "
                          "(one trace serves the whole fleet)")
 
-    _, _, opt = _make_raw_steps(cfg, trainer, backend)
-    inputs, scheds = [], []
-    with activate(tracer), span("schedule", n_devices=D):
-        for d, s in enumerate(seeds):
-            tsp = dataclasses.replace(trainer, seed=int(s))
-            # Per-chip data shard: disjoint strided training slices of
-            # the one stream, equal-sized so one trace serves the fleet.
-            chip_tasks = (shard_tasks(tasks, D, d) if shard_data
-                          else tasks)
-            inp, sched = _build_seed_inputs(cfg, tsp, rspec, backend,
-                                            chip_tasks, opt)
-            if inp is None:
-                raise ValueError("run_fleet needs a shape-uniform task "
-                                 "stream (ragged schedules cannot share "
-                                 "the fleet trace)")
-            inputs.append(inp)
-            scheds.append(sched)
+    # Per-chip data shard: disjoint strided training slices of the one
+    # stream, equal-sized so one trace serves the fleet (cut inside the
+    # schedule span, chip by chip).
+    prepared = _prepare_seeds(
+        cfg, trainer, rspec, backend,
+        ((s, shard_tasks(tasks, D, d) if shard_data else tasks)
+         for d, s in enumerate(seeds)),
+        tracer=tracer, n_devices=D)
+    if prepared is None:
+        raise ValueError("run_fleet needs a shape-uniform task "
+                         "stream (ragged schedules cannot share "
+                         "the fleet trace)")
+    inputs, scheds = prepared
 
     n_tasks = len(tasks)
     S = inputs[0].xs.shape[1]
     track_writes = backend.tracker is not None or tele.enabled
     in_graph = get_policy_class(rspec.resolved_policy).in_graph
-    if tele.enabled:
-        # Host-side replay-traffic credit, once per chip's schedule —
-        # the same accounting as run_compiled's seed loop.
-        T, F = tasks[0].x_train.shape[1:]
-        for sched in scheds:
-            traffic = _ingraph_replay_traffic(
-                rspec, trainer.batch_size, sched.steps_per_task,
-                (T, F)) if in_graph else sched.replay_traffic
-            if traffic:
-                tele.record(traffic)
     # The program's telemetry deltas, taken out at its flush point while
     # it traces (once per shard body, scaled by the chips it holds) and
     # credited on the host after each execution, once per shard.
@@ -276,14 +262,10 @@ def run_fleet(cfg, spec: TrainerSpec, tasks: list[TaskData],
         wcounts = res.pop("wcounts")
         per_device_wcounts = None
         if track_writes and wcounts:
-            per_device_wcounts = {k: np.asarray(v)
-                                  for k, v in wcounts.items()}
-            counts = {k: v.sum(axis=0)
-                      for k, v in per_device_wcounts.items()}
-            total_steps = n_tasks * S * D
-            tele.meter_write_counts(counts, total_steps)
-            if backend.tracker is not None:
-                backend.tracker.record_counts(counts, total_steps)
+            per_device_wcounts = wcounts
+            _credit_writes(backend, {k: v.sum(axis=0)
+                                     for k, v in wcounts.items()},
+                           n_tasks * S * D)
 
         per_device = [_summarize_run(res["R_full"][i],
                                      res["baseline_row"][i],

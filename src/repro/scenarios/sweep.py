@@ -32,7 +32,12 @@ count maps and folded into the telemetry/endurance tracker host-side.
 
 Scenarios whose streams are not shape-uniform across tasks cannot scan;
 :func:`run_compiled` falls back to the Python loop for those and says so
-in the result (``"compiled": False``).
+in the result (``"compiled": False``) — unless a
+:class:`repro.data.ragged.PadPolicy` pads them onto one shape. There is
+one program either way: a padded schedule's masks (steps, rows, true
+lengths; the eval sets' rows and lengths) are optional trailing inputs
+of the same run and the same step, which trace the unmasked program
+without them.
 
 Device substrates with a fused recurrence (wbs/analog) ride it inside
 the compiled sweep automatically — the step functions come from the same
@@ -53,7 +58,7 @@ import collections
 import contextlib
 import dataclasses
 import time
-from typing import Any, Callable, Optional, Sequence, Union
+from typing import Any, Callable, Iterable, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
@@ -68,6 +73,7 @@ from repro.core.continual import (ReplaySpec, TrainerSpec,
                                   run_continual)
 from repro.core.replay import _split_chain
 from repro.replay import get_policy_class, ingraph_init
+from repro.data.ragged import needs_masked_program
 from repro.data.synthetic import TaskData
 from repro.obs.tracer import activate, span
 from repro.scenarios.metrics import continual_metrics
@@ -85,9 +91,9 @@ __all__ = ["clear_program_cache", "run_compiled", "run_sweep",
 @dataclasses.dataclass
 class _SeedInputs:
     """Everything one seed's compiled run consumes. The mask fields are
-    populated only on schedules built under a
-    :class:`repro.data.ragged.PadPolicy` (the step axis is padded to the
-    longest task; masks say what is real)."""
+    populated only where the schedule needs the masked program
+    (:func:`repro.data.ragged.needs_masked_program`): the step axis is
+    padded to the longest task and the masks say what is real."""
     params: Any
     opt_state: Any
     dev_state: Any
@@ -101,19 +107,20 @@ class _SeedInputs:
     lengths: Any = None     # (n_tasks, S, B) int32 true sequence lengths
 
     def as_arrays(self) -> tuple:
-        """The positional argument tuple ``_make_run_fn``'s run consumes
-        (minus the shared eval buffers) — one definition used by the
+        """The leading positional arguments of ``_make_run_fn``'s run
+        (before the shared eval buffers) — one definition used by the
         seed-vmapped path here and the fleet runner's device axis."""
         return (self.params, self.opt_state, self.dev_state, self.rstate,
                 jnp.asarray(self.xs), jnp.asarray(self.ys),
                 jnp.asarray(self.step_keys), jnp.asarray(self.eval_keys))
 
-    def as_masked_arrays(self) -> tuple:
-        """``as_arrays`` plus the validity masks — the argument tuple of
-        ``_make_masked_run_fn``'s run."""
-        return self.as_arrays() + (jnp.asarray(self.step_valid),
-                                   jnp.asarray(self.row_valid),
-                                   jnp.asarray(self.lengths))
+    def masks(self) -> Optional[tuple]:
+        """The run's ``masks`` argument: the three masks on the device,
+        or None for the unmasked program."""
+        if self.step_valid is None:
+            return None
+        return tuple(jnp.asarray(m) for m in
+                     (self.step_valid, self.row_valid, self.lengths))
 
 
 def _pad_step_axis(a: np.ndarray, s_max: int, fill=0) -> np.ndarray:
@@ -126,18 +133,25 @@ def _pad_step_axis(a: np.ndarray, s_max: int, fill=0) -> np.ndarray:
 
 def _build_seed_inputs(cfg, trainer: TrainerSpec, rspec: ReplaySpec,
                        backend: DeviceBackend, tasks: list[TaskData],
-                       opt, pad=None) -> tuple[_SeedInputs, Any]:
+                       opt, pad=None, eval_padded: bool = False
+                       ) -> tuple[_SeedInputs, Any]:
     """Materialize one seed's schedule, initial state and PRNG streams —
-    the exact sequences :func:`run_continual` would consume.
+    the exact sequences :func:`run_continual` would consume. None for
+    the inputs when the schedule is ragged and there is no ``pad``.
 
     With ``pad`` (a :class:`repro.data.ragged.PadPolicy`) a ragged
-    stream never bails to the loop: per-task step counts pad to the
-    longest task with ``step_valid`` masks (the PRNG chain is split over
-    the *real* step count only, so it stays bit-identical to the loop's;
-    pad steps consume dummy zero keys whose results the scan discards).
+    stream never bails to the loop: where the run needs masks
+    (:func:`repro.data.ragged.needs_masked_program`, with
+    ``eval_padded`` from :func:`repro.data.ragged.pad_tasks`), per-task
+    step counts pad to the longest task with ``step_valid`` masks (the
+    PRNG chain is split over the *real* step count only, so it stays
+    bit-identical to the loop's; pad steps consume dummy zero keys whose
+    results the scan discards). Otherwise the inputs carry no masks.
     """
     schedule = build_batch_schedule(trainer, rspec, tasks, pad=pad)
-    if pad is None and not schedule.uniform:
+    masked = pad is not None and needs_masked_program(pad, eval_padded,
+                                                      schedule)
+    if not masked and not schedule.uniform:
         return None, schedule
     key, params, psi, dev_state = _init_run(cfg, trainer, backend)
     opt_state = opt.init(params) if trainer.algo == "adam" else {"psi": psi}
@@ -156,7 +170,7 @@ def _build_seed_inputs(cfg, trainer: TrainerSpec, rspec: ReplaySpec,
     if get_policy_class(rspec.resolved_policy).in_graph:
         T, F = tasks[0].x_train.shape[1:]
         rstate = ingraph_init(rspec.capacity, (T, F), rspec.bits)
-    if pad is None:
+    if not masked:
         return _SeedInputs(
             params=params, opt_state=opt_state, dev_state=dev_state,
             xs=np.stack(schedule.x), ys=np.stack(schedule.y),
@@ -182,6 +196,52 @@ def _build_seed_inputs(cfg, trainer: TrainerSpec, rspec: ReplaySpec,
     ), schedule
 
 
+def _prepare_seeds(cfg, trainer: TrainerSpec, rspec: ReplaySpec,
+                   backend: DeviceBackend, seed_tasks: Iterable, *,
+                   tracer, pad=None, eval_padded: bool = False,
+                   **span_args
+                   ) -> Optional[tuple[list[_SeedInputs], list]]:
+    """Every seed's inputs and schedule for one compiled run, shared by
+    :func:`run_compiled` and the fleet runner: built under one
+    ``schedule`` span (``span_args`` its arguments) from ``seed_tasks``,
+    pairs of a trainer seed and the task stream it trains on, and the
+    replay DRAM traffic of every schedule credited to the backend's
+    telemetry once — the host buffer's (host policies) or the exact
+    scan-carried buffer's (in-graph policies). None, crediting nothing,
+    when a schedule cannot scan (see :func:`_build_seed_inputs`)."""
+    _, _, opt = _make_raw_steps(cfg, trainer, backend)
+    inputs, scheds = [], []
+    with activate(tracer), span("schedule", **span_args):
+        for seed, tasks in seed_tasks:
+            inp, sched = _build_seed_inputs(
+                cfg, dataclasses.replace(trainer, seed=int(seed)), rspec,
+                backend, tasks, opt, pad=pad, eval_padded=eval_padded)
+            if inp is None:
+                return None
+            inputs.append(inp)
+            scheds.append(sched)
+    tele = backend.telemetry
+    if tele.enabled:
+        in_graph = get_policy_class(rspec.resolved_policy).in_graph
+        T, F = inputs[0].xs.shape[-2:]
+        for sched in scheds:
+            traffic = _ingraph_replay_traffic(
+                rspec, trainer.batch_size, sched.steps_per_task,
+                (T, F)) if in_graph else sched.replay_traffic
+            if traffic:
+                tele.record(traffic)
+    return inputs, scheds
+
+
+def _credit_writes(backend: DeviceBackend, counts: dict,
+                   events: int) -> None:
+    """Credit the write-count maps a compiled run summed over ``events``
+    training steps to the backend's telemetry and endurance tracker."""
+    backend.telemetry.meter_write_counts(counts, events)
+    if backend.tracker is not None:
+        backend.tracker.record_counts(counts, events)
+
+
 # ---------------------------------------------------------------------------
 # The compiled run
 # ---------------------------------------------------------------------------
@@ -190,7 +250,8 @@ def _make_run_fn(cfg, trainer: TrainerSpec, backend: DeviceBackend,
                  n_tasks: int, S: int, track_writes: bool, baseline: bool,
                  ingraph_rspec: Optional[ReplaySpec] = None,
                  obs_metrics: bool = False,
-                 flush: Optional[Callable[[], None]] = None):
+                 flush: Optional[Callable[[], None]] = None,
+                 real_steps: Optional[int] = None):
     """Build the jitted whole-protocol run. When ``ingraph_rspec`` names
     an in-graph replay policy (loss_aware), the step is the replay-
     wrapped one and the device-resident buffer rides the scan carry —
@@ -202,8 +263,21 @@ def _make_run_fn(cfg, trainer: TrainerSpec, backend: DeviceBackend,
     the training results are unchanged; False (the default) emits
     exactly the pre-obs trace.
 
-    ``flush`` is called once, at the top level of the trace, where the
-    pending telemetry deltas are complete; by default
+    The run's optional trailing ``masks`` (``step_valid``, ``row_valid``,
+    ``lengths``, each with leading (n_tasks, S)) and ``eval_masks``
+    (``eval_valid``, ``eval_len``) are a padded ragged schedule's
+    (:mod:`repro.data.ragged`): rows and sequence lengths reach the step
+    and the eval, and a pad step's results are discarded by a carry
+    select on ``step_valid``. Without them the trace is the unmasked
+    one. In-graph replay and obs metric streams are unmasked only
+    (:func:`run_compiled` refuses them on a masked run).
+
+    Telemetry meters the step body ``real_steps`` times (by default
+    ``n_tasks * S``; a padded schedule's pad steps are free, its pad rows
+    and timesteps inside an executed batch are not: the chip streams
+    them, docs/data.md), the eval map ``n_tasks`` times after each task
+    and the baseline once. ``flush`` is called once, at the top level of
+    the trace, where the pending deltas are complete; by default
     ``Telemetry.emit_pending`` embeds them in an io_callback."""
     raw_train, raw_eval, _ = _make_raw_steps(cfg, trainer, backend)
     ingraph_step = None
@@ -214,39 +288,58 @@ def _make_run_fn(cfg, trainer: TrainerSpec, backend: DeviceBackend,
         from repro.obs.runlog import step_stats
     tele = backend.telemetry
     flush = flush if flush is not None else tele.emit_pending
+    steps = n_tasks * S if real_steps is None else real_steps
 
     def run(params, opt_state, dev_state, rstate, xs, ys, step_keys,
-            eval_keys, eval_x, eval_y):
+            eval_keys, eval_x, eval_y, masks=None, eval_masks=None):
+        step_valid, row_valid, lengths = masks or (None,) * 3
+        eval_valid, eval_len = eval_masks or (None,) * 2
 
-        def eval_all(p, k_eval, dstate):
-            def one(exy):
-                return raw_eval(p, k_eval, exy[0], exy[1], dstate)
-            with tele.scaled(n_tasks):
-                return jax.lax.map(one, (eval_x, eval_y))
+        def eval_all(p, k_eval, dstate, scale):
+            def one(e):
+                ex, ey, ev, el = e
+                return raw_eval(p, k_eval, ex, ey, dstate, ev, el)
+            with tele.scaled(scale):
+                return jax.lax.map(one, (eval_x, eval_y,
+                                         eval_valid, eval_len))
 
         def task_body(carry, inp):
-            xs_t, ys_t, keys_t, k_eval, r_on = inp
+            xs_t, ys_t, keys_t, k_eval, r_on, sv_t, rv_t, ln_t = inp
 
             def step_body(c, sinp):
                 p, o, d, wc, rs = c
-                x, y, k = sinp
+                x, y, k, sv, rv, ln = sinp
                 if ingraph_step is not None:
-                    p, o, loss, applied, d, rs = ingraph_step(
+                    p2, o2, loss, applied, d2, rs = ingraph_step(
                         p, o, k, x, y, d, rs, r_on)
                 else:
-                    p, o, loss, applied, d = raw_train(p, o, k, x, y, d)
+                    p2, o2, loss, applied, d2 = raw_train(p, o, k, x, y,
+                                                          d, rv, ln)
+                if sv is not None:
+                    # Step-axis padding: compute-and-discard. The pad
+                    # step's dummy key was never split from the loop's
+                    # chain, so keeping the incoming carry preserves
+                    # PRNG parity.
+                    def keep(new, old):
+                        return jax.tree.map(
+                            lambda a, b: jnp.where(sv, a, b), new, old)
+                    p2, o2, d2 = keep(p2, p), keep(o2, o), keep(d2, d)
+                    loss = jnp.where(sv, loss, 0.0)
                 if wc is not None:
-                    wc = {n: wc[n] + (applied[n] != 0).astype(jnp.int32)
-                          for n in wc}
+                    def hits(a):
+                        h = (a != 0).astype(jnp.int32)
+                        return h if sv is None else jnp.where(sv, h, 0)
+                    wc = {n: wc[n] + hits(applied[n]) for n in wc}
                 ys_out = (loss, *step_stats(applied, rs)) \
                     if obs_metrics else loss
-                return (p, o, d, wc, rs), ys_out
+                return (p2, o2, d2, wc, rs), ys_out
 
-            with tele.scaled(S):
-                carry, step_ys = jax.lax.scan(step_body, carry,
-                                              (xs_t, ys_t, keys_t))
+            with tele.scaled(steps):
+                carry, step_ys = jax.lax.scan(
+                    step_body, carry, (xs_t, ys_t, keys_t, sv_t, rv_t,
+                                       ln_t))
             p, _, d, _, _ = carry
-            accs = eval_all(p, k_eval, d)
+            accs = eval_all(p, k_eval, d, n_tasks * n_tasks)
             return carry, (accs, step_ys)
 
         wc0 = {n: zeros_like_varying(p.shape, jnp.int32, params, xs)
@@ -254,13 +347,12 @@ def _make_run_fn(cfg, trainer: TrainerSpec, backend: DeviceBackend,
                if jnp.ndim(p) >= 2} if track_writes else None
         replay_on = jnp.arange(n_tasks) > 0
         with tele.deferred():
-            base_row = eval_all(params, eval_keys[0], dev_state) \
+            base_row = eval_all(params, eval_keys[0], dev_state, n_tasks) \
                 if baseline else jnp.zeros((n_tasks,), jnp.float32)
-            with tele.scaled(n_tasks):
-                carry, (R_full, step_ys) = jax.lax.scan(
-                    task_body,
-                    (params, opt_state, dev_state, wc0, rstate),
-                    (xs, ys, step_keys, eval_keys, replay_on))
+            carry, (R_full, step_ys) = jax.lax.scan(
+                task_body, (params, opt_state, dev_state, wc0, rstate),
+                (xs, ys, step_keys, eval_keys, replay_on, step_valid,
+                 row_valid, lengths))
         flush()
         params, opt_state, dev_state, wcounts, rstate = carry
         if obs_metrics:
@@ -274,96 +366,6 @@ def _make_run_fn(cfg, trainer: TrainerSpec, backend: DeviceBackend,
             out["obs"] = {"write_pulses": pulses, "dg_mag": dgs,
                           "replay_occupancy": occs}
         return out
-
-    return run
-
-
-def _make_masked_run_fn(cfg, trainer: TrainerSpec, backend: DeviceBackend,
-                        n_tasks: int, total_real_steps: int,
-                        track_writes: bool, baseline: bool,
-                        flush: Callable[[], None]):
-    """The masked twin of :func:`_make_run_fn` for padded ragged
-    schedules: row-validity/true-length aware steps
-    (:func:`repro.core.continual._make_masked_steps`), step-axis padding
-    discarded by a ``jnp.where`` carry select on ``step_valid``, and
-    telemetry metered for the real step total only (padded rows and
-    timesteps *inside* an executed batch still meter — the chip streams
-    them; see docs/data.md).
-
-    On a stream with no actual raggedness (``PadPolicy(force=True)``)
-    every mask is all-true, the carry select is the identity, and the
-    outputs agree with ``_make_run_fn``'s to float32 ulp-level (the
-    tolerance contract of :mod:`repro.data.ragged`, gated in
-    benchmarks/data_bench.py). In-graph replay is unsupported here
-    (:func:`run_compiled` raises before getting this far), so
-    ``rstate`` never rides the carry. ``flush`` is the program's one
-    telemetry flush point, as in :func:`_make_run_fn`."""
-    from repro.core.continual import _make_masked_steps
-    raw_train, raw_eval, _ = _make_masked_steps(cfg, trainer, backend)
-    tele = backend.telemetry
-
-    def run(params, opt_state, dev_state, rstate, xs, ys, step_keys,
-            eval_keys, step_valid, row_valid, lengths,
-            eval_x, eval_y, eval_valid, eval_len):
-        del rstate  # host-materialized policies only on the masked path
-
-        def eval_all(p, k_eval, dstate, scale):
-            def one(args):
-                ex, ey, ev, el = args
-                return raw_eval(p, k_eval, ex, ey, dstate, ev, el)
-            with tele.scaled(scale):
-                return jax.lax.map(one, (eval_x, eval_y,
-                                         eval_valid, eval_len))
-
-        def task_body(carry, inp):
-            xs_t, ys_t, keys_t, k_eval, sv_t, rv_t, ln_t = inp
-
-            def step_body(c, sinp):
-                p, o, d, wc = c
-                x, y, k, sv, rv, ln = sinp
-                p2, o2, loss, applied, d2 = raw_train(p, o, k, x, y, d,
-                                                      rv, ln)
-                # Step-axis padding: compute-and-discard. The pad step's
-                # dummy key was never split from the loop's chain, so
-                # keeping the incoming carry preserves PRNG parity.
-                def keep(new, old):
-                    return jax.tree.map(
-                        lambda a, b: jnp.where(sv, a, b), new, old)
-                p, o, d = keep(p2, p), keep(o2, o), keep(d2, d)
-                loss = jnp.where(sv, loss, 0.0)
-                if wc is not None:
-                    wc = {n: wc[n] + jnp.where(
-                        sv, (applied[n] != 0).astype(jnp.int32), 0)
-                        for n in wc}
-                return (p, o, d, wc), loss
-
-            # One scale for the whole (padded) step scan: the real step
-            # total across tasks. On a uniform stream this equals the
-            # unmasked program's nested S × n_tasks product exactly.
-            with tele.scaled(total_real_steps):
-                carry, losses_t = jax.lax.scan(
-                    step_body, carry,
-                    (xs_t, ys_t, keys_t, sv_t, rv_t, ln_t))
-            p, _, d, _ = carry
-            accs = eval_all(p, k_eval, d, n_tasks * n_tasks)
-            return carry, (accs, losses_t)
-
-        wc0 = {n: zeros_like_varying(p.shape, jnp.int32, params, xs)
-               for n, p in params.items()
-               if jnp.ndim(p) >= 2} if track_writes else None
-        with tele.deferred():
-            base_row = eval_all(params, eval_keys[0], dev_state,
-                                n_tasks) \
-                if baseline else jnp.zeros((n_tasks,), jnp.float32)
-            carry, (R_full, losses) = jax.lax.scan(
-                task_body, (params, opt_state, dev_state, wc0),
-                (xs, ys, step_keys, eval_keys, step_valid,
-                 row_valid, lengths))
-        flush()
-        params, opt_state, dev_state, wcounts = carry
-        return {"params": params, "dev_state": dev_state,
-                "R_full": R_full, "losses": losses,
-                "wcounts": wcounts, "baseline_row": base_row}
 
     return run
 
@@ -564,12 +566,13 @@ def run_compiled(cfg, spec: TrainerSpec, tasks: list[TaskData],
 
     ``pad`` is a :class:`repro.data.ragged.PadPolicy`: ragged streams
     (unequal n_train/n_test/sequence length across tasks) pad onto one
-    bucketed shape with validity masks and run *compiled* through the
-    masked program instead of falling back to the loop. With a policy
-    attached but nothing actually ragged (and ``force=False``), the
-    exact pre-refactor unmasked program runs — bitwise-identical
-    outputs. Masked runs keep host-materialized replay only (an
-    in-graph policy raises) and do not support obs metric streams.
+    bucketed shape and run *compiled* instead of falling back to the
+    loop, with the validity masks as extra inputs of the one program.
+    With a policy attached but nothing actually ragged (and
+    ``force=False``) no masks are passed and the unmasked trace runs —
+    bitwise-identical outputs. Masked runs keep host-materialized
+    replay only (an in-graph policy raises) and do not support obs
+    metric streams.
     """
     trainer = spec
     if not isinstance(trainer, TrainerSpec):
@@ -596,35 +599,29 @@ def run_compiled(cfg, spec: TrainerSpec, tasks: list[TaskData],
     seed_list = list(seeds) if seeds is not None else None
     many = seed_list is not None and len(seed_list) > 1
 
-    if not uniform and pad is None:
+    if (not uniform and pad is None) or len(test_shapes) != 1:
         # Declared ragged (ScenarioSpec.uniform=False) with no padding
-        # policy: skip schedule materialization and run the loop.
+        # policy, or unequal eval sets: skip schedule materialization
+        # and run the loop.
         return _fallback_python(cfg, trainer, tasks, rspec, backend,
                                 seed_list, obs=obs)
 
-    _, _, opt = _make_raw_steps(cfg, trainer, backend)
-    inputs, scheds = [], []
-    with activate(tracer), span("schedule", n_tasks=len(tasks)):
-        for s in (seed_list if seed_list is not None else [trainer.seed]):
-            tsp = dataclasses.replace(trainer, seed=s)
-            inp, sched = _build_seed_inputs(cfg, tsp, rspec, backend,
-                                            tasks, opt, pad=pad)
-            inputs.append(inp)
-            scheds.append(sched)
-    if any(i is None for i in inputs) or len(test_shapes) != 1:
-        # The materialized schedules are discarded — their replay
-        # traffic is *not* credited here; the loop fallback meters its
-        # own (run_continual records its schedule's traffic).
+    prepared = _prepare_seeds(
+        cfg, trainer, rspec, backend,
+        [(s, tasks) for s in (seed_list if seed_list is not None
+                              else [trainer.seed])],
+        tracer=tracer, pad=pad, eval_padded=eval_padded,
+        n_tasks=len(tasks))
+    if prepared is None:
+        # A ragged schedule: nothing was credited; the loop fallback
+        # meters its own (run_continual records its schedule's traffic).
         return _fallback_python(cfg, trainer, tasks, rspec, backend,
                                 seed_list, obs=obs)
-
-    masked = False
-    if pad is not None:
-        from repro.data.ragged import needs_masked_program
-        # The mask *structure* (step counts, row/length masks present)
-        # is seed-independent — only the shuffled content differs — so
-        # one schedule decides for all seeds.
-        masked = needs_masked_program(pad, eval_padded, scheds[0])
+    inputs, scheds = prepared
+    # The mask *structure* (step counts, row/length masks present) is
+    # seed-independent — only the shuffled content differs — so every
+    # seed's inputs agree on it.
+    masked = inputs[0].step_valid is not None
     if masked and obs_on:
         raise ValueError(
             "obs metric streams are not supported on the masked "
@@ -634,17 +631,6 @@ def run_compiled(cfg, spec: TrainerSpec, tasks: list[TaskData],
     S = inputs[0].xs.shape[1]
     total_real = sum(scheds[0].steps_per_task)
     track_writes = backend.tracker is not None or tele.enabled
-    if tele.enabled:
-        # Credit the replay DRAM traffic of every schedule this compiled
-        # run will actually consume (host policies), or the exact
-        # scan-carried buffer traffic (in-graph policies) — once.
-        T, F = tasks[0].x_train.shape[1:]
-        for sched in scheds:
-            traffic = _ingraph_replay_traffic(
-                rspec, trainer.batch_size, sched.steps_per_task,
-                (T, F)) if in_graph else sched.replay_traffic
-            if traffic:
-                tele.record(traffic)
     # The program's telemetry deltas, taken out at its flush point while
     # it traces and credited below after it runs.
     deltas: dict[str, int] = {}
@@ -652,46 +638,36 @@ def run_compiled(cfg, spec: TrainerSpec, tasks: list[TaskData],
     def flush():
         deltas.update(tele.take_pending())
 
-    if masked:
-        run = _make_masked_run_fn(cfg, trainer, backend, n_tasks,
-                                  total_real, track_writes, baseline,
-                                  flush)
-    else:
-        run = _make_run_fn(cfg, trainer, backend, n_tasks, S,
-                           track_writes, baseline,
-                           ingraph_rspec=rspec if in_graph else None,
-                           obs_metrics=obs_on, flush=flush)
+    run = _make_run_fn(cfg, trainer, backend, n_tasks, S,
+                       track_writes, baseline,
+                       ingraph_rspec=rspec if in_graph else None,
+                       obs_metrics=obs_on, flush=flush,
+                       real_steps=total_real)
 
     eval_x = jnp.asarray(np.stack([t.x_test for t in tasks]))
     eval_y = jnp.asarray(np.stack([t.y_test for t in tasks]))
-    eval_extra = ()
+    eval_m = None
     if masked:
         from repro.data.ragged import eval_masks
-        ev_valid, ev_len = eval_masks(tasks)
-        eval_extra = (jnp.asarray(ev_valid), jnp.asarray(ev_len))
-    n_seed_args = 11 if masked else 8
+        eval_m = tuple(map(jnp.asarray, eval_masks(tasks)))
 
     # Donate the mutated state buffers (params; the conductance pairs).
     # opt_state is excluded: DFA's is the pass-through Ψ and XLA declines
     # to alias the Adam moments on CPU — donating either only warns.
     # Vmapped leaves don't alias at all.
     donate = (0, 2) if not many else ()
+    per_seed_args = [i.as_arrays() + (i.masks(),) for i in inputs]
     if many:
-        stacked = jax.tree.map(
-            lambda *xs: jnp.stack(xs),
-            *[(i.as_masked_arrays() if masked else i.as_arrays())
-              for i in inputs])
+        stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *per_seed_args)
         fn = jax.jit(jax.vmap(
-            run, in_axes=(0,) * n_seed_args
-            + (None,) * (2 + len(eval_extra))))
+            run, in_axes=(0,) * 8 + (None, None, 0, None)))
         scope = tele.scaled(len(seed_list))
     else:
-        stacked = inputs[0].as_masked_arrays() if masked \
-            else inputs[0].as_arrays()
+        stacked = per_seed_args[0]
         fn = jax.jit(run, donate_argnums=donate)
         scope = contextlib.nullcontext()
 
-    args = (*stacked, eval_x, eval_y, *eval_extra)
+    args = (*stacked[:8], eval_x, eval_y, stacked[8], eval_m)
     # Everything the trace reads. No traced function reads the trainer's
     # seed: seeds enter as the arguments' values.
     key = _program_key(
@@ -714,21 +690,15 @@ def run_compiled(cfg, spec: TrainerSpec, tasks: list[TaskData],
     # summed (the Python loop meters these per step in record_endurance).
     # Masked runs zeroed the pad steps' pulses in-graph, so the event
     # count is the real step total.
-    total_steps = (total_real if masked else n_tasks * S) \
-        * (len(seed_list) if many else 1)
     wcounts = res.pop("wcounts")
     if track_writes and wcounts:
-        counts = {k: (v.sum(axis=0) if many else v)
-                  for k, v in wcounts.items()}
-        tele.meter_write_counts(counts, total_steps)
-        if backend.tracker is not None:
-            backend.tracker.record_counts(counts, total_steps)
+        _credit_writes(backend, {k: (v.sum(axis=0) if many else v)
+                                 for k, v in wcounts.items()},
+                       total_real * (len(seed_list) if many else 1))
 
     def _trim(losses):
-        # Masked runs pad the step axis; report real steps only, in the
-        # same task-major order the loop's loss list uses.
-        if not masked:
-            return losses
+        # Real steps only (a masked run pads the step axis), in the same
+        # task-major order the loop's loss list uses.
         return np.concatenate(
             [np.asarray(losses[t, :st])
              for t, st in enumerate(scheds[0].steps_per_task)])

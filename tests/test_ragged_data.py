@@ -173,6 +173,32 @@ def test_forced_masked_program_matches_within_ulp(algo):
 
 
 @pytest.mark.parametrize("algo", ["dfa", "adam"])
+def test_forced_masks_meter_what_the_unmasked_program_meters(algo):
+    """On an aligned stream the masked program's counters are the
+    unmasked one's: all-true masks cost the chip nothing. Write pulses
+    are data-dependent, and the two programs may differ by an ulp, so
+    only they are left out."""
+    from repro.backends import get_backend
+    from repro.telemetry.meters import WRITE_PULSES
+    tasks = _aligned_tasks()
+    cfg = scenario_miru_config(tasks, n_h=24)
+    tr = TrainerSpec(algo=algo, epochs_per_task=1, batch_size=32, seed=0)
+    rp = ReplaySpec(capacity=48)
+
+    def counters(pad):
+        be = get_backend("wbs")
+        be.telemetry.enable()
+        res = run_compiled(cfg, tr, tasks, rp, be, pad=pad)
+        assert res["compiled"]
+        return {k: v for k, v in be.telemetry.snapshot().items()
+                if not k.startswith(WRITE_PULSES)}
+
+    base = counters(None)
+    assert base
+    assert counters(PadPolicy(force=True)) == base
+
+
+@pytest.mark.parametrize("algo", ["dfa", "adam"])
 @pytest.mark.parametrize("last_batch", ["pad", "drop"])
 def test_ragged_loop_vs_compiled(algo, last_batch):
     """A genuinely ragged stream through the one compiled program holds
@@ -240,6 +266,24 @@ def test_in_graph_replay_rejects_padding():
         run_compiled(cfg, tr, tasks, rp, "ideal", pad=PadPolicy(force=True))
     with pytest.raises(ValueError, match="in-graph|loss_aware"):
         run_continual(cfg, tr, tasks, rp, "ideal", pad=PadPolicy(force=True))
+
+
+def test_obs_metric_streams_reject_masks():
+    """The obs metric streams read per-step scalars of an unpadded step
+    axis, so the compiled sweep refuses them where the run takes masks
+    and keeps them where a PadPolicy leaves the stream unmasked."""
+    from repro.obs import ObsSpec
+    tasks = _aligned_tasks()
+    cfg = scenario_miru_config(tasks, n_h=16)
+    tr = TrainerSpec(algo="dfa", epochs_per_task=1, batch_size=32, seed=0)
+    rp = ReplaySpec(capacity=32)
+    obs = ObsSpec(metrics=True)
+    with pytest.raises(ValueError, match="obs metric streams"):
+        run_compiled(cfg, tr, tasks, rp, "ideal", obs=obs,
+                     pad=PadPolicy(force=True))
+    res = run_compiled(cfg, tr, tasks, rp, "ideal", obs=obs,
+                       pad=PadPolicy())
+    assert "runlog" in res
 
 
 # ---------------------------------------------------------------------------
